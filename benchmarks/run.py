@@ -171,6 +171,8 @@ def main() -> None:
                     help="write this run's report as a fresh baseline")
     args = ap.parse_args()
     selected = args.only.split(",") if args.only else list(SUITES)
+    from repro.stream.compile import use_compile_cache
+    use_compile_cache()
 
     print("name,us_per_call,derived")
     report: Dict[str, Any] = {"suites": {}, "meta": {}, "failures": []}

@@ -176,6 +176,8 @@ def main() -> None:
     ap.add_argument("--plan-cache-size", type=int, default=128,
                     help="signature-keyed plan cache LRU capacity")
     args = ap.parse_args()
+    from repro.stream.compile import use_compile_cache
+    use_compile_cache()
     if args.command == "trace":
         # the trace demo runs the jit query backend by default (unless
         # the caller pinned one) so the export carries compile-layer

@@ -353,21 +353,23 @@ class BigDawg:
 
         ``alias`` picks the registry architecture (``lm``/``moe``/
         ``rwkv6``/``mamba`` map to reduced-config registry archs; a full
-        registry name like ``olmoe-1b-7b`` also works with an explicit
-        ``alias``).  The catalog object is named ``models.<alias>`` —
-        dotted, so the Planner's signature extractor sees it as a
-        referenced object and pins infer reads to the model's home
-        engine.  Params are derived from a fixed seed at first use and
-        cached per (arch, seed), so every deployment (sharded, replayed,
-        front-door) scores with bit-identical weights."""
-        from repro.stream.ml import MLModel, resolve_arch
+        registry name like ``olmoe-1b-7b``, as ``alias`` or ``arch``,
+        loads that architecture's published config).  The catalog
+        object is named ``models.<alias>`` — dotted, so the Planner's
+        signature extractor sees it as a referenced object and pins
+        infer reads to the model's home engine.  Params are derived
+        from a fixed seed at first use and cached per (arch, seed,
+        reduced), so every deployment (sharded, replayed, front-door)
+        scores with bit-identical weights."""
+        from repro.stream.ml import ALIASES, MLModel, resolve_arch
         self.ensure_ml_engines(
             max(1, int(engine_name[len("mlhost"):]) + 1)
             if engine_name.startswith("mlhost")
             and engine_name[len("mlhost"):].isdigit() else 1)
-        handle = MLModel(name=f"models.{alias}",
-                         arch=resolve_arch(arch or alias), seed=seed,
-                         home_engine=engine_name)
+        name = arch or alias
+        handle = MLModel(name=f"models.{alias}", arch=resolve_arch(name),
+                         seed=seed, home_engine=engine_name,
+                         reduced=name in ALIASES)
         self.register_object(engine_name, handle.name, handle,
                              fields=("window", "rows", "score"))
         return handle

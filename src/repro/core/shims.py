@@ -244,17 +244,24 @@ def _balanced(s: str) -> Tuple[str, int]:
 
 
 def _split_args(s: str) -> List[str]:
-    parts, depth, cur = [], 0, []
+    """Split on top-level commas.  Quoted text (a cast schema such as
+    ``'<a:int32>[i=0:*,10,0]'``) never splits; outside quotes ``<`` and
+    ``>`` are comparisons (``filter(A, v>1)``), not brackets."""
+    parts, depth, quote, cur = [], 0, None, []
     for ch in s:
-        if ch in "([<{":
+        if quote:
+            quote = None if ch == quote else quote
+        elif ch in "'\"":
+            quote = ch
+        elif ch in "([{":
             depth += 1
-        elif ch in ")]>}":
+        elif ch in ")]}":
             depth -= 1
-        if ch == "," and depth == 0:
+        elif ch == "," and depth == 0:
             parts.append("".join(cur).strip())
             cur = []
-        else:
-            cur.append(ch)
+            continue
+        cur.append(ch)
     if cur:
         parts.append("".join(cur).strip())
     return parts

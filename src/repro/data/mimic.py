@@ -142,7 +142,9 @@ def stream_mimic_paired_waveforms(bd: BigDawg, *, batch_rows: int = 48,
     ``ts + U(-jitter, jitter)``), so insertion buffers and watermarks do
     real work, while ``jitter < max_delay / 2`` guarantees no row is
     ever late — the streams reconstruct the exact in-order signal.
-    Yields a per-batch dict with append counts, both watermarks, and the
+    Yields a per-batch dict with append counts, the rows appended in
+    arrival order (``rows``: stream name -> columns, so a caller can
+    rebuild any answer from the raw feed), both watermarks, and the
     standing queries that ran on that tick; after the final batch both
     streams are flushed (punctuation) and one more tick runs so standing
     joins see the last closed window.
@@ -186,7 +188,9 @@ def stream_mimic_paired_waveforms(bd: BigDawg, *, batch_rows: int = 48,
         ran = bd.streams.tick() if tick else []
         yield {**_emit(b, ran), "appended": {
             abp_name: counts_abp["appended"],
-            ecg_name: counts_ecg["appended"]}}
+            ecg_name: counts_ecg["appended"]},
+            "rows": {abp_name: {"ts": abp_ts, "abp": abp},
+                     ecg_name: {"ts": ecg_ts, "ecg": ecg}}}
     # punctuation: close the tail windows and let standing joins see them
     for s in streams.values():
         s.flush()

@@ -4,10 +4,9 @@ from __future__ import annotations
 
 import jax
 
+from repro.kernels import interpret_mode
 from repro.kernels.flash_attention import flash_attention as k
 from repro.kernels.flash_attention import ref
-
-_INTERPRET = jax.default_backend() != "tpu"
 
 
 def flash_attention(q: jax.Array, kk: jax.Array, v: jax.Array, *,
@@ -20,4 +19,4 @@ def flash_attention(q: jax.Array, kk: jax.Array, v: jax.Array, *,
         # ragged tails fall back to the oracle (kernel wants aligned tiles)
         return ref.gqa_attention(q, kk, v, causal=causal)
     return k.flash_attention(q, kk, v, causal=causal, block_q=bq,
-                             block_k=bk, interpret=_INTERPRET)
+                             block_k=bk, interpret=interpret_mode(q))

@@ -20,10 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax<0.5 names this TPUCompilerParams; jax>=0.5 renamed it
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 DEFAULT_BD = 256
 DEFAULT_CHUNK = 64
 
@@ -93,7 +89,7 @@ def selective_scan_chunked(u, dt, a, b, c, *, bd: int = DEFAULT_BD,
         ],
         scratch_shapes=[pltpu.VMEM((bd, n), jnp.float32)],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(u, dt, a, b, c)
     return y, h

@@ -6,10 +6,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.mamba_scan import mamba_scan as k
 from repro.kernels.mamba_scan import ref
-
-_INTERPRET = jax.default_backend() != "tpu"
 
 
 def selective_scan(u, dt, a, b, c, h0=None, *, bd: int = k.DEFAULT_BD,
@@ -22,7 +21,7 @@ def selective_scan(u, dt, a, b, c, h0=None, *, bd: int = k.DEFAULT_BD,
             else jnp.zeros((bsz, di, n), jnp.float32)
         return ref.selective_scan(u, dt, a, b, c, h_init)
     y, h = k.selective_scan_chunked(u, dt, a, b, c, bd=bd, chunk=chunk,
-                                    interpret=_INTERPRET)
+                                    interpret=interpret_mode(u))
     if h0 is not None:
         # linear-in-state: add decayed-h0 contributions
         dtf = dt.astype(jnp.float32)

@@ -10,10 +10,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import interpret_mode
 from repro.kernels.quant_cast import quant_cast as k
 from repro.kernels.quant_cast import ref
-
-_INTERPRET = jax.default_backend() != "tpu"
 
 
 def _pad_to_tiles(flat: jax.Array) -> Tuple[jax.Array, int]:
@@ -35,7 +34,7 @@ def quantize(x: jax.Array, block: int = k.BLOCK, *, use_kernel: bool = True
     flat = x.astype(jnp.float32).reshape(-1)
     x2d, _ = _pad_to_tiles(flat)
     if use_kernel:
-        q, scale = k.quantize_2d(x2d, interpret=_INTERPRET)
+        q, scale = k.quantize_2d(x2d, interpret=interpret_mode(x2d))
     else:
         q, scale = ref.quantize_blocks(x2d)
     return q, scale
@@ -44,7 +43,8 @@ def quantize(x: jax.Array, block: int = k.BLOCK, *, use_kernel: bool = True
 def dequantize(q: jax.Array, scale: jax.Array, shape, *,
                use_kernel: bool = True) -> jax.Array:
     if use_kernel:
-        x2d = k.dequantize_2d(q, scale, interpret=_INTERPRET)
+        x2d = k.dequantize_2d(q, scale,
+                              interpret=interpret_mode(q))
     else:
         x2d = ref.dequantize_blocks(q, scale)
     n = int(np.prod(shape))

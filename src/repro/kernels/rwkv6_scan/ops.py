@@ -11,10 +11,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.rwkv6_scan import ref
 from repro.kernels.rwkv6_scan import rwkv6_scan as k
-
-_INTERPRET = jax.default_backend() != "tpu"
 
 
 def wkv6(r, kk, v, w, u, state=None, *, chunk: int = k.DEFAULT_CHUNK):
@@ -25,7 +24,7 @@ def wkv6(r, kk, v, w, u, state=None, *, chunk: int = k.DEFAULT_CHUNK):
             else jnp.zeros((b, h, d, d), jnp.float32)
         return ref.wkv6(r, kk, v, w, u, s0)
     y, s_fin = k.wkv6_chunked(r, kk, v, w, u, chunk=chunk,
-                              interpret=_INTERPRET)
+                              interpret=interpret_mode(r))
     if state is not None:
         # fold the incoming carry: the kernel ran with S_0 = 0, and the
         # recurrence is linear in the state, so add the decayed-carry terms.

@@ -7,18 +7,12 @@ import (launch/dryrun.py lines 1-2).
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5 explicit-sharding API
-    from jax.sharding import AxisType
-except ImportError:  # older jax.sharding has no AxisType / axis_types kwarg
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes):
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

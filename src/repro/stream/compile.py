@@ -16,13 +16,11 @@ streaming op family —
   aggregate(window(S,n),f) rolling aggregate   (lowered to the O(1)
                                                cumulative-ring lookup —
                                                already the optimal plan
-                                               stage — or the Pallas
-                                               min/max scan kernel)
+                                               stage)
   aggregate(<window>, f)   windowed aggregate  (compiled gather feeding
                                                the data model's jnp
                                                reduction unchanged)
-  join(W1, W2, on, tol)    banded interval join (device searchsorted /
-                                               Pallas bound search +
+  join(W1, W2, on, tol)    banded interval join (device searchsorted +
                                                pair expansion over
                                                padded buckets)
 
@@ -36,25 +34,24 @@ House invariant: the compiled path is **bit-identical** to the
 interpreter.  Every lowering is exact by construction — gathers and
 dynamic slices move bits, the join matcher is integer index math over
 the same widened float64 keys the interpreter searches, the rolling
-aggregate reuses the same cumulative-ring subtraction (sum/avg are
-order-sensitive, so they never leave it; min/max are exactly
-associative, so the Pallas scan may take them), and windowed aggregates
-feed the identical jnp reduction the interpreter calls — and every
-output passes through the same dtype canonicalization the interpreter
-applies.  The jit-parity CI lane runs the property + event-time suites
+aggregate reuses the same cumulative-ring subtraction, and windowed
+aggregates feed the identical jnp reduction the interpreter calls — and
+every output passes through the same dtype canonicalization the
+interpreter applies.  The jit-parity CI lane runs the property + event-time suites
 under both backends and diffs results.
 
 x64/platform config (the bayespec exemplar): stream rings are float64,
 and jax downcasts to float32 by default, so compiled computation runs
-inside a **scoped** ``jax.experimental.enable_x64`` context — exact
-float64 in the kernels, zero config leakage into the rest of the
-process — and outputs cast back to the ambient default dtype inside
-the jitted function, which is bitwise what the interpreter's
-``jnp.asarray`` does to its float64 numpy results.  This module is the
-only place allowed to touch jax config (ruff TID251 bans
-``jax.config.update`` everywhere else; ``jax_enable_x64`` /
-``set_platform`` below are the explicit process-wide switches for
-operators who want global x64 or a TPU backend).
+inside a **scoped** ``jax.enable_x64`` context — exact float64 in the
+kernels, zero config leakage into the rest of the process — and outputs
+come back float64 and pass through the same host ``jnp.asarray`` the
+interpreter applies to its float64 numpy results (a device-side
+float64 -> float32 convert would flush subnormals to zero, which the
+host cast keeps).  This module is the only place allowed to touch jax
+config (ruff TID251 bans ``jax.config.update`` everywhere else;
+``jax_enable_x64`` below is the explicit process-wide switch for
+operators who want global x64, and ``use_compile_cache`` the one
+entry points call to keep compiled programs across processes).
 
 Backend selection: ``REPRO_QUERY_BACKEND=interpreter`` (default) or
 ``jit``, read per query so tests can flip it per-case.  Queries outside
@@ -67,6 +64,7 @@ from __future__ import annotations
 
 import functools
 import os
+import pathlib
 import re
 import threading
 import weakref
@@ -76,17 +74,17 @@ import numpy as np
 
 from repro.core import datamodel as dm
 from repro.obs import metrics, trace
-from repro.stream import kernels
 from repro.stream.engine import (_COMBINABLE_AGGS, ShardedStream, Stream,
                                  StreamException, _latest_closed_ewindow)
 
 try:                                         # gate: jax may be absent
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64 as _x64_scope
     JAX_AVAILABLE = True
-except Exception:                            # noqa: BLE001 — optional dep
-    jax = jnp = _x64_scope = None            # type: ignore
+except ModuleNotFoundError as exc:           # any other import error raises
+    if exc.name != "jax":
+        raise
+    jax = jnp = None                         # type: ignore
     JAX_AVAILABLE = False
 
 BACKEND_ENV = "REPRO_QUERY_BACKEND"
@@ -162,23 +160,18 @@ def jax_enable_x64(use_x64: Optional[bool] = None) -> None:
     jax.config.update("jax_enable_x64", bool(use_x64))
 
 
-def set_platform(platform: Optional[str] = None) -> None:
-    """Pin jax's platform, honoring ``JAX_PLATFORMS`` when no explicit
-    value is given (CI sets ``JAX_PLATFORMS=cpu``; on a TPU host pass
-    ``"tpu"``)."""
-    if not JAX_AVAILABLE:
-        return
-    if platform is None:
-        platform = os.environ.get("JAX_PLATFORMS", "cpu")
-    jax.config.update("jax_platform_name", platform.split(",")[0])
-
-
-def _out_dtype():
-    """The dtype the interpreter's ``jnp.asarray`` canonicalizes float64
-    to under the *current global* config — compiled outputs cast to the
-    same, so parity holds with or without process-wide x64.  Pure host
-    dtype math (no device dispatch: this runs on every tick)."""
-    return jax.dtypes.canonicalize_dtype(np.float64)
+def use_compile_cache() -> str:
+    """Keep jax's compiled programs in a persistent cache, for entry
+    points only (never called at import).  ``JAX_COMPILATION_CACHE_DIR``
+    wins when set — jax reads it itself and nothing is overridden;
+    otherwise the cache is ``<repo>/.jax_cache``, a fixed path because
+    the path is part of every entry's key.  Returns the directory."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(pathlib.Path(__file__).resolve().parents[3] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def _pow2(n: int) -> int:
@@ -191,22 +184,20 @@ def _pow2(n: int) -> int:
 
 
 # -- jitted primitives ------------------------------------------------------
-# All of these trace under the scoped x64 context (float64 in, exact),
-# and cast to the interpreter's canonical dtype as the last op.
+# All of these trace under the scoped x64 context (float64 in, float64
+# out, exact); the host canonicalizes the dtype like the interpreter.
 
 @functools.partial(jax.jit if JAX_AVAILABLE else lambda f, **k: f,
-                   static_argnames=("size", "out_dtype"))
-def _jit_tumbling(cols, off, size, out_dtype):
+                   static_argnames=("size",))
+def _jit_tumbling(cols, off, size):
     """(F, capacity) ordered ring -> (F, size) window at offset ``off``
     (always fully in bounds: the eviction check ran on the host)."""
-    out = jax.lax.dynamic_slice(cols, (0, off), (cols.shape[0], size))
-    return out.astype(out_dtype)
+    return jax.lax.dynamic_slice(cols, (0, off), (cols.shape[0], size))
 
 
 @functools.partial(jax.jit if JAX_AVAILABLE else lambda f, **k: f,
-                   static_argnames=("size", "slide", "max_windows",
-                                    "out_dtype"))
-def _jit_sliding(cols, size, slide, max_windows, out_dtype):
+                   static_argnames=("size", "slide", "max_windows"))
+def _jit_sliding(cols, size, slide, max_windows):
     """(F, capacity) ordered ring -> (F, max_windows, size) stacked
     sliding windows — replacing the interpreter's Python stacking loop.
     Every window start is static (``max_windows`` keeps the last slice
@@ -215,18 +206,18 @@ def _jit_sliding(cols, size, slide, max_windows, out_dtype):
     past the live count hold garbage the host slices away."""
     wins = [jax.lax.slice_in_dim(cols, i * slide, i * slide + size,
                                  axis=1) for i in range(max_windows)]
-    return jnp.stack(wins, axis=1).astype(out_dtype)
+    return jnp.stack(wins, axis=1)
 
 
 @functools.partial(jax.jit if JAX_AVAILABLE else lambda f, **k: f,
-                   static_argnames=("length", "out_dtype"))
-def _jit_rows(cols, off, length, out_dtype):
+                   static_argnames=("length",))
+def _jit_rows(cols, off, length):
     """(F, capacity) -> (F, length) rows starting at ``off`` — the
     ewindow gather, clip-indexed so the static padded length never
     reads out of bounds; the host slices the live prefix."""
     idx = off + jnp.arange(length, dtype=jnp.int32)
     idx = jnp.clip(idx, 0, cols.shape[1] - 1)
-    return cols[:, idx].astype(out_dtype)
+    return cols[:, idx]
 
 
 @functools.partial(jax.jit if JAX_AVAILABLE else lambda f, **k: f)
@@ -245,21 +236,9 @@ def _jit_join_bounds(lt, rt, tol):
     return lo, hi, order
 
 
-@functools.partial(jax.jit if JAX_AVAILABLE else lambda f, **k: f)
-def _jit_join_bounds_pallas(lt, rt, tol):
-    """The Pallas lowering of the bound search (REPRO_STREAM_PALLAS=1):
-    same (lo, hi, order) by construction — the kernel's bisection is
-    bit-identical to searchsorted on sorted keys."""
-    order = jnp.argsort(rt, stable=True)
-    rs = rt[order]
-    lo, hi = kernels.join_bounds(lt, rs, tol)
-    return lo.astype(order.dtype), hi.astype(order.dtype), order
-
-
 @functools.partial(jax.jit if JAX_AVAILABLE else lambda f, **k: f,
-                   static_argnames=("pairs", "out_dtype"))
-def _jit_join_gather(lcols, rcols, lt, rt, lo, cum, order,
-                     pairs, out_dtype):
+                   static_argnames=("pairs",))
+def _jit_join_gather(lcols, rcols, lt, rt, lo, cum, order, pairs):
     """Expand (lo, counts) into the interpreter's pair list — ordered by
     left row, then right timestamp — and gather both sides plus
     ``dt = r.on - l.on``.  Pure integer index math and one float64
@@ -272,10 +251,7 @@ def _jit_join_gather(lcols, rcols, lt, rt, lo, cum, order,
     prev = jnp.where(row > 0, cum[jnp.maximum(row - 1, 0)], 0)
     slot = jnp.clip(lo[row] + (k - prev), 0, order.shape[0] - 1)
     ri = order[slot]
-    l_out = lcols[:, row].astype(out_dtype)
-    r_out = rcols[:, ri].astype(out_dtype)
-    dt = (rt[ri] - lt[row]).astype(out_dtype)
-    return l_out, r_out, dt
+    return lcols[:, row], rcols[:, ri], rt[ri] - lt[row]
 
 
 # -- query parsing (the compiled op family) ---------------------------------
@@ -375,10 +351,8 @@ def _compile_window(stream, size: int,
                 raise StreamException(
                     f"stream {stream.name!r}: window [{s},{s + size}) "
                     f"already evicted (buffer starts at {first_seq})")
-            out_dtype = _out_dtype()         # ambient, outside the scope
-            with _x64_scope():
-                out = _jit_tumbling(stacked, s - first_seq, size=size,
-                                    out_dtype=out_dtype)
+            with jax.enable_x64(True):
+                out = _jit_tumbling(stacked, s - first_seq, size=size)
             # zero-copy np view, numpy slicing, one device_put per
             # field: eager jax slicing on the host path costs ~0.5ms
             # *per op* in dispatch, which would swamp the jitted gather
@@ -400,11 +374,9 @@ def _compile_window(stream, size: int,
                 f"stream {stream.name!r}: {count} rows < window "
                 f"size {size}")
         num = (count - size) // slide + 1
-        out_dtype = _out_dtype()             # ambient, outside the scope
-        with _x64_scope():
+        with jax.enable_x64(True):
             out = _jit_sliding(stacked, size=size, slide=slide,
-                               max_windows=max_windows,
-                               out_dtype=out_dtype)
+                               max_windows=max_windows)
         arr = np.asarray(out)                # zero-copy; slice in numpy
         return dm.ArrayObject(
             {f: jnp.asarray(arr[j, :num]) for j, f in enumerate(fields)},
@@ -436,10 +408,8 @@ def _compile_ewindow(stream, span: float,
             for j, f in enumerate(fields):
                 stacked[j, :count] = stream._ordered(f)
         m = b - a
-        out_dtype = _out_dtype()             # ambient, outside the scope
-        with _x64_scope():
-            out = _jit_rows(stacked, a, length=_pow2(max(m, 1)),
-                            out_dtype=out_dtype)
+        with jax.enable_x64(True):
+            out = _jit_rows(stacked, a, length=_pow2(max(m, 1)))
         arr = np.asarray(out)                # zero-copy; slice in numpy
         return dm.ArrayObject(
             {f: jnp.asarray(arr[j, :m]) for j, f in enumerate(fields)},
@@ -460,37 +430,6 @@ def _compile_aggregate(engine, expr: str, fn: str,
             raise Uncompilable("non-rolling tumbling aggregate")
         if size <= 0:
             raise Uncompilable("non-positive window size")
-
-        if (fn in ("min", "max") and kernels.enabled()
-                and isinstance(stream, Stream)):
-            # the Pallas rolling scan: min/max are exactly associative,
-            # so the kernel's evaluation order cannot diverge from the
-            # interpreter's window-slice reduction
-            def run_kernel() -> dm.ArrayObject:
-                with stream._lock:
-                    total = stream.total_appended
-                    count = stream._count
-                    k = total // size - 1
-                    if k < 0:
-                        raise StreamException(
-                            f"stream {stream.name!r}: no complete "
-                            f"window of size {size} yet ({total} rows)")
-                    s, e = k * size, (k + 1) * size
-                    first_seq = total - count
-                    if s < first_seq:
-                        raise StreamException(
-                            f"stream {stream.name!r}: window [{s},{e}) "
-                            f"already evicted (buffer starts at "
-                            f"{first_seq})")
-                    sl = stream._ordered(field)[s - first_seq:
-                                                e - first_seq]
-                with _x64_scope():
-                    value = float(np.asarray(kernels.window_minmax(
-                        jnp.asarray(sl[None, :]), fn == "max"))[0])
-                return dm.ArrayObject(
-                    {f"{fn}_{field}": jnp.asarray([value])}, ("i",))
-
-            return CompiledStreamQuery("rolling", run_kernel)
 
         # rolling fast path: lowered to the O(1) cumulative-ring lookup
         # (already the optimal plan stage — identical memo, identical
@@ -569,7 +508,6 @@ def _compile_join(engine, left_expr: str, right_expr: str,
         # (interval_join's contract), so one compiled matcher serves
         # both; only the partial-join accounting follows the bands
         bands_eff = max(1, min(int(bands), nl or 1))
-        out_dtype = _out_dtype()
         if nl == 0 or nr == 0:
             l_out = np.zeros((len(la), 0), np.float64)
             r_out = np.zeros((len(ra), 0), np.float64)
@@ -586,10 +524,8 @@ def _compile_join(engine, left_expr: str, right_expr: str,
             rcols = np.zeros((len(ra), rb), np.float64)
             for j, f in enumerate(ra):
                 rcols[j, :nr] = ra[f]
-            bounds = (_jit_join_bounds_pallas if kernels.enabled()
-                      else _jit_join_bounds)
-            with _x64_scope():
-                lo, hi, order = bounds(lt_pad, rt_pad, t)
+            with jax.enable_x64(True):
+                lo, hi, order = _jit_join_bounds(lt_pad, rt_pad, t)
                 # zero-copy np views + numpy slicing (eager jax host
                 # slices cost ~0.5ms/op in dispatch)
                 lo_np = np.asarray(lo)[:nl]
@@ -604,7 +540,7 @@ def _compile_join(engine, left_expr: str, right_expr: str,
                     l_dev, r_dev, dt_dev = _jit_join_gather(
                         lcols, rcols, lt_pad, rt_pad,
                         jnp.asarray(lo_np), jnp.asarray(cum), order,
-                        pairs=_pow2(pairs), out_dtype=out_dtype)
+                        pairs=_pow2(pairs))
                     l_out = np.asarray(l_dev)[:, :pairs]
                     r_out = np.asarray(r_dev)[:, :pairs]
                     dt = np.asarray(dt_dev)[:pairs]

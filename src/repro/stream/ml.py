@@ -12,7 +12,9 @@ The island has one operation:
 Each window's chosen field is quantized into token ids (deterministic
 per-window min/max binning over the float64 row values — the same rows
 always produce the same tokens, on any shard layout or replay) and run
-through ``registry.forward`` on the model's reduced config; the score is
+through ``registry.forward`` — on the reduced config for the short
+aliases (``lm``/``moe``/``rwkv6``/``mamba``), on the published config
+for an explicit registry arch name; the score is
 the mean next-token NLL in float32 — an anomaly signal: windows the
 model finds unlikely score high.  The result is a relational Table with
 one row per window (``window``/``rows``/``score``), so scores ride the
@@ -68,7 +70,9 @@ try:  # pragma: no cover - exercised by monkeypatching JAX_AVAILABLE
     from repro.serve.engine import TickWaveScheduler
     from repro.sharding import logical as _logical
     JAX_AVAILABLE = True
-except Exception:  # noqa: BLE001
+except ModuleNotFoundError as exc:  # any other import error raises
+    if exc.name != "jax":
+        raise
     jax = jnp = registry = _logical = None
     TickWaveScheduler = None
     JAX_AVAILABLE = False
@@ -107,6 +111,7 @@ class MLModel:
     arch: str                      # registry architecture
     seed: int = 0                  # PRNG seed for the cached params
     home_engine: str = "mlhost0"
+    reduced: bool = True           # registered by alias: reduced preset
 
     def nbytes(self) -> int:
         return 0                   # the handle itself holds no tensors
@@ -135,7 +140,7 @@ class _Loaded:
     forward: Any                   # jitted (params, tokens) -> logits
 
 
-_LOADED: Dict[Tuple[str, int], _Loaded] = {}
+_LOADED: Dict[Tuple[str, int, bool], _Loaded] = {}
 _WAVE = TickWaveScheduler() if TickWaveScheduler is not None else None
 _STATS: Dict[str, int] = {
     "models_loaded": 0, "params_cache_hits": 0, "infer_executions": 0,
@@ -151,15 +156,15 @@ def stats() -> Dict[str, Any]:
     return out
 
 
-def load_model(arch: str, seed: int = 0) -> _Loaded:
-    """The per-(arch, seed) params + jitted-forward cache.  Params are
-    derived from a fixed PRNGKey, so every deployment that registers
-    the same model scores with bit-identical weights."""
-    key = (arch, seed)
+def load_model(arch: str, seed: int = 0, reduced: bool = True) -> _Loaded:
+    """The per-(arch, seed, reduced) params + jitted-forward cache.
+    Params are derived from a fixed PRNGKey, so every deployment that
+    registers the same model scores with bit-identical weights."""
+    key = (arch, seed, reduced)
     if key in _LOADED:
         _STATS["params_cache_hits"] += 1
         return _LOADED[key]
-    cfg = registry.get_config(arch, reduced=True)
+    cfg = registry.get_config(arch, reduced=reduced)
     params = _logical.init_params(jax.random.PRNGKey(seed),
                                   registry.param_specs(cfg))
     fwd = jax.jit(lambda p, toks: registry.forward(
@@ -304,7 +309,7 @@ def execute_ml(engine: Engine, query: str) -> dm.Table:
         raise MLException(f"{model_name!r} is not an MLModel handle")
 
     def run() -> dm.Table:
-        loaded = load_model(handle.arch, handle.seed)
+        loaded = load_model(handle.arch, handle.seed, handle.reduced)
         windows, n = _window_values(engine, window_expr, kwargs.get("field"))
         scores, rows = [], []
         for i, vals in enumerate(windows):

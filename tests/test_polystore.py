@@ -90,6 +90,17 @@ def test_array_island_filter(bd):
     assert int(r.value.mask().sum()) == 256 - 151
 
 
+def test_array_island_aggregate_over_filter(bd):
+    # a comparison inside a nested operator must not unbalance the
+    # argument split (">" is not a bracket outside a quoted schema)
+    r = bd.query("bdarray(aggregate(filter(mimic2v26.waveform,"
+                 " signal>1.0), count(signal)))")
+    full = np.asarray(bd.engines["densehbm0"].get(
+        "mimic2v26.waveform").attrs["signal"])
+    assert int(np.asarray(r.value.attrs["count_signal"])[0]) == \
+        int((full > 1.0).sum())
+
+
 def test_array_island_aggregate(bd):
     r = bd.query("bdarray(aggregate(mimic2v26.waveform, avg(signal)))")
     got = float(np.asarray(next(iter(r.value.attrs.values())))[0])

@@ -14,6 +14,7 @@ The flake-hunter workflow re-runs this file 5x at REPRO_MAX_WORKERS=8
 (nightly + stream-path PRs) to shake out lock-order regressions."""
 import threading
 
+import jax
 import numpy as np
 import pytest
 
@@ -295,8 +296,11 @@ def test_concurrent_rolling_aggregate_matches_recompute():
     assert not errors
     size = 1024
     rolling = sh.window_aggregate(size, "sum", "v")
-    materialized = float(np.asarray(sh.window(size).attrs["v"],
-                                    np.float64).sum())
+    # materialize at the ring's float64: the default float32 view alone
+    # moves a 1024-row sum by ~1e-6 (ROADMAP D2)
+    with jax.enable_x64(True):
+        materialized = float(np.asarray(sh.window(size).attrs["v"],
+                                        np.float64).sum())
     # cumulative-ring range sums differ from a cold recompute only by
     # float64 rounding (same tolerance the stream bench asserts)
     assert rolling == pytest.approx(materialized, abs=1e-6)

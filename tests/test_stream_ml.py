@@ -183,10 +183,10 @@ def test_params_cache_shared_across_queries():
     bd.query(q)
     bd.query(q)
     s1 = ml.stats()
-    # the (arch, seed) entry was loaded at most once this test; the
-    # second execution is always a cache hit
+    # the (arch, seed, reduced) entry was loaded at most once this test;
+    # the second execution is always a cache hit
     assert s1["params_cache_hits"] - s0["params_cache_hits"] >= 1
-    assert ("qwen2-1.5b", 0) in ml._LOADED
+    assert ("qwen2-1.5b", 0, True) in ml._LOADED
 
 
 # -- front door ---------------------------------------------------------------
