@@ -263,6 +263,9 @@ class Executor:
                     raise LocalQueryExecutionException(
                         f"{node.island} query failed on {engine.name}: "
                         f"{node.query!r}: {exc}") from exc
+                # under tracing the node ends when its device work is
+                # done; untraced, the result stays asynchronous
+                trace.device_wait(value)
             dt = time.perf_counter() - t0
             task_stages[("node", nid)] = [
                 (f"{node.island} query ({engine.name})", dt)]

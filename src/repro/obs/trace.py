@@ -4,10 +4,19 @@ thread pools (DAG executor workers, producer staging threads, ordered
 commit lanes) so parent links survive thread hops.
 
 Gated by ``REPRO_TRACE`` (default off).  When disabled, ``span()``
-returns a shared no-op context manager and ``bind()`` returns its
-argument unchanged — the instrumented hot paths pay one module-global
-check plus a kwargs dict, nothing else (the ``stream/trace_overhead``
-bench row keeps this honest).
+returns a shared no-op context manager, ``bind()`` returns its
+argument unchanged and ``device_wait()`` returns at once — the
+instrumented hot paths pay one module-global check plus a kwargs dict,
+nothing else (the chip benchmark's end-to-end runs, made with tracing
+off, keep this honest).
+
+While enabled, every span is also a ``jax.profiler.TraceAnnotation``
+under its own name (``stream/tick`` a ``StepTraceAnnotation`` with the
+tick number), so a profiler capture shows the program's spans on the
+same clock as the device's operations.  That happens only once jax is
+imported: this module never imports it.  ``device_wait(value)`` ends a
+span when the device work behind ``value`` is done, not when it was
+enqueued.
 
 Span names are ``layer/stage`` (``planner/query``, ``executor/node``,
 ``committer/commit``, ``stream/tick``, ``compile/execute`` ...); the
@@ -18,16 +27,19 @@ ids, valid across threads.
 
 Exporters: ``chrome_trace()`` (Perfetto-loadable trace-event JSON with
 flow events marking cross-thread parent links) and ``flamegraph()``
-(text summary aggregated by parent-chain path).  Spans slower than
+(text summary aggregated by parent-chain path, with each path's self
+time from ``self_times()``).  Spans slower than
 ``REPRO_SLOW_OP_MS`` additionally land in the slow-op ring with their
 attrs (``slow_ops()``).
 """
 from __future__ import annotations
 
 import collections
+import dataclasses
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from contextvars import ContextVar
@@ -133,10 +145,22 @@ class _NoopSpan:
 NOOP = _NoopSpan()
 
 
+def _annotation(name: str, attrs: Dict[str, Any]):
+    """The profiler annotation of a span, or None where jax is not
+    imported (the span then lives on ``_clock`` alone)."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)
+    if profiler is None:
+        return None
+    if name == "stream/tick" and "tick" in attrs:
+        return profiler.StepTraceAnnotation(name, step_num=attrs["tick"])
+    return profiler.TraceAnnotation(name)
+
+
 class Span:
     """An open span; records itself into the ring on exit."""
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "attrs",
-                 "_t0", "_token")
+                 "_t0", "_token", "_ann")
 
     def __init__(self, name: str, trace_id: Optional[str],
                  attrs: Dict[str, Any]) -> None:
@@ -147,6 +171,7 @@ class Span:
         self.parent_id: Optional[int] = None
         self._t0 = 0.0
         self._token = None
+        self._ann = None
 
     def set(self, **attrs: Any) -> None:
         self.attrs.update(attrs)
@@ -161,11 +186,16 @@ class Span:
             self.trace_id = f"t{next(_TRACE_IDS)}"
         self.span_id = next(_SPAN_IDS)
         self._token = _CURRENT.set(self)
+        self._ann = _annotation(self.name, self.attrs)
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = _clock()
         return self
 
     def __exit__(self, exc_type: Any, exc: Any, tb: Any) -> bool:
         duration = _clock() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         _CURRENT.reset(self._token)
         if exc_type is not None:
             self.attrs.setdefault("error", exc_type.__name__)
@@ -193,6 +223,25 @@ def span(name: str, trace_id: Optional[str] = None, **attrs: Any):
     if not _ENABLED:
         return NOOP
     return Span(name, trace_id, attrs)
+
+
+def device_wait(value: Any) -> Any:
+    """Wait, in a ``device/wait`` span, until every jax array of
+    ``value`` (a pytree, or a data-model dataclass whose fields hold
+    them) is computed, and return ``value``.  Returns at once while
+    tracing is off, so untraced callers keep jax's asynchronous
+    dispatch."""
+    if not _ENABLED:
+        return value
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return value
+    tree = value
+    if dataclasses.is_dataclass(value) and not isinstance(value, type):
+        tree = vars(value)
+    with Span("device/wait", None, {}):
+        jax.block_until_ready(tree)
+    return value
 
 
 def bind(fn):
@@ -238,6 +287,29 @@ def reset() -> None:
     with _LOCK:
         _SPANS.clear()
         _SLOW.clear()
+
+
+def self_times(records: List[SpanRecord]) -> Dict[int, float]:
+    """Span id -> self seconds: each span's duration minus the union of
+    its children's intervals clipped to it.  Children are the records
+    whose ``parent_id`` is the span's, on any thread, so overlapping
+    children (a pool's workers) are not counted twice."""
+    children: Dict[int, List[SpanRecord]] = {}
+    for r in records:
+        if r.parent_id is not None:
+            children.setdefault(r.parent_id, []).append(r)
+    out: Dict[int, float] = {}
+    for r in records:
+        lo, hi = r.start, r.start + r.duration
+        covered, reach = 0.0, lo
+        for a, b in sorted((max(c.start, lo), min(c.start + c.duration, hi))
+                           for c in children.get(r.span_id, ())):
+            a = max(a, reach)
+            if b > a:
+                covered += b - a
+                reach = b
+        out[r.span_id] = r.duration - covered
+    return out
 
 
 # -- exporters ----------------------------------------------------------------
@@ -291,10 +363,12 @@ def flamegraph(records: Optional[List[SpanRecord]] = None,
                max_rows: int = 40) -> str:
     """Text flamegraph: spans aggregated by their parent-chain path
     (``stream/tick;planner/query;executor/node``), each path showing
-    total milliseconds, call count and share of root time.  A span whose
-    parent was evicted from the ring roots its own path."""
+    total milliseconds, self milliseconds (``self_times``), call count
+    and share of root time.  A span whose parent was evicted from the
+    ring roots its own path."""
     records = spans() if records is None else list(records)
     by_id = {r.span_id: r for r in records}
+    own = self_times(records)
     totals: Dict[tuple, List[float]] = {}
     root_ms = 0.0
     for r in records:
@@ -306,19 +380,20 @@ def flamegraph(records: Optional[List[SpanRecord]] = None,
             path.append(parent.name)
             cur, hops = parent, hops + 1
         path_t = tuple(reversed(path))
-        bucket = totals.setdefault(path_t, [0.0, 0])
+        bucket = totals.setdefault(path_t, [0.0, 0.0, 0])
         bucket[0] += r.duration * 1e3
-        bucket[1] += 1
+        bucket[1] += own[r.span_id] * 1e3
+        bucket[2] += 1
         if len(path_t) == 1:
             root_ms += r.duration * 1e3
-    lines = [f"{'total_ms':>10} {'calls':>7}  path "
+    lines = [f"{'total_ms':>10} {'self_ms':>10} {'calls':>7}  path "
              f"({len(records)} spans)"]
     ranked = sorted(totals.items(), key=lambda kv: kv[0])
-    for path_t, (ms, calls) in ranked[:max_rows]:
+    for path_t, (ms, self_ms, calls) in ranked[:max_rows]:
         share = f" {100.0 * ms / root_ms:5.1f}%" if root_ms else ""
         indent = "  " * (len(path_t) - 1)
-        lines.append(f"{ms:10.2f} {calls:7d}  {indent}{path_t[-1]}"
-                     f"{share}")
+        lines.append(f"{ms:10.2f} {self_ms:10.2f} {calls:7d}  "
+                     f"{indent}{path_t[-1]}{share}")
     if len(ranked) > max_rows:
         lines.append(f"... {len(ranked) - max_rows} more paths")
     return "\n".join(lines)

@@ -366,12 +366,13 @@ class FrontDoor:
                        if name in self._by_cq_name]
             fanouts = [(shared.subs[:], response)
                        for shared, response in targets]
-        for subs, response in fanouts:
-            for sub in subs:
-                before = sub.dropped
-                sub._push(tick_no, response.value)
-                delivered += 1
-                dropped += sub.dropped - before
+        with trace.span("serve/fanout", tick=tick_no):
+            for subs, response in fanouts:
+                for sub in subs:
+                    before = sub.dropped
+                    sub._push(tick_no, response.value)
+                    delivered += 1
+                    dropped += sub.dropped - before
         if fanouts:
             took = time.perf_counter() - t0
             with self._lock:

@@ -75,7 +75,8 @@ import numpy as np
 from repro.core import datamodel as dm
 from repro.obs import metrics, trace
 from repro.stream.engine import (_COMBINABLE_AGGS, ShardedStream, Stream,
-                                 StreamException, _latest_closed_ewindow)
+                                 StreamException, _latest_closed_ewindow,
+                                 to_device)
 
 try:                                         # gate: jax may be absent
     import jax
@@ -320,12 +321,14 @@ def _export_stacked(stream: Stream) -> Tuple[int, int, np.ndarray]:
     """(total_appended, count, (F, capacity) zero-padded oldest-first
     rows) — one point-in-time ring export; the lock is held only for
     the gather copy, exactly like the interpreter's ``_ordered`` reads."""
-    with stream._lock:
+    with trace.span("stream/gather", stream=stream.name,
+                    path="compiled") as sp, stream._lock:
         count = stream._count
         total = stream.total_appended
         out = np.zeros((len(stream.fields), stream.capacity), np.float64)
         for j, f in enumerate(stream.fields):
             out[j, :count] = stream._ordered(f)
+        sp.set(rows=count)
     return total, count, out
 
 
@@ -352,13 +355,14 @@ def _compile_window(stream, size: int,
                     f"stream {stream.name!r}: window [{s},{s + size}) "
                     f"already evicted (buffer starts at {first_seq})")
             with jax.enable_x64(True):
-                out = _jit_tumbling(stacked, s - first_seq, size=size)
+                out = _jit_tumbling(to_device(stacked), s - first_seq,
+                                    size=size)
             # zero-copy np view, numpy slicing, one device_put per
             # field: eager jax slicing on the host path costs ~0.5ms
             # *per op* in dispatch, which would swamp the jitted gather
-            arr = np.asarray(out)
+            arr = np.asarray(trace.device_wait(out))
             return dm.ArrayObject(
-                {f: jnp.asarray(arr[j]) for j, f in enumerate(fields)},
+                {f: to_device(arr[j]) for j, f in enumerate(fields)},
                 ("tick",))
 
         return CompiledStreamQuery("window", run)
@@ -375,11 +379,11 @@ def _compile_window(stream, size: int,
                 f"size {size}")
         num = (count - size) // slide + 1
         with jax.enable_x64(True):
-            out = _jit_sliding(stacked, size=size, slide=slide,
+            out = _jit_sliding(to_device(stacked), size=size, slide=slide,
                                max_windows=max_windows)
-        arr = np.asarray(out)                # zero-copy; slice in numpy
+        arr = np.asarray(trace.device_wait(out))  # zero-copy; slice in numpy
         return dm.ArrayObject(
-            {f: jnp.asarray(arr[j, :num]) for j, f in enumerate(fields)},
+            {f: to_device(arr[j, :num]) for j, f in enumerate(fields)},
             ("window", "tick"))
 
     return CompiledStreamQuery("window", run_sliding)
@@ -395,7 +399,8 @@ def _compile_ewindow(stream, span: float,
 
     def run() -> dm.ArrayObject:
         start, end = _latest_closed_ewindow(stream, span, slide)
-        with stream._lock:
+        with trace.span("stream/gather", stream=stream.name,
+                        path="compiled") as sp, stream._lock:
             if start <= stream._evicted_ts:
                 raise StreamException(
                     f"stream {stream.name!r}: ewindow [{start},{end}) "
@@ -407,12 +412,14 @@ def _compile_ewindow(stream, span: float,
                                np.float64)
             for j, f in enumerate(fields):
                 stacked[j, :count] = stream._ordered(f)
+            sp.set(rows=count)
         m = b - a
         with jax.enable_x64(True):
-            out = _jit_rows(stacked, a, length=_pow2(max(m, 1)))
-        arr = np.asarray(out)                # zero-copy; slice in numpy
+            out = _jit_rows(to_device(stacked), a,
+                            length=_pow2(max(m, 1)))
+        arr = np.asarray(trace.device_wait(out))  # zero-copy; slice in numpy
         return dm.ArrayObject(
-            {f: jnp.asarray(arr[j, :m]) for j, f in enumerate(fields)},
+            {f: to_device(arr[j, :m]) for j, f in enumerate(fields)},
             ("tick",))
 
     return CompiledStreamQuery("ewindow", run)
@@ -438,7 +445,7 @@ def _compile_aggregate(engine, expr: str, fn: str,
         def run_rolling() -> dm.ArrayObject:
             value = stream.window_aggregate(size, fn, field)
             return dm.ArrayObject(
-                {f"{fn}_{field}": jnp.asarray([value])}, ("i",))
+                {f"{fn}_{field}": to_device([value])}, ("i",))
 
         return CompiledStreamQuery("rolling", run_rolling)
 
@@ -525,7 +532,9 @@ def _compile_join(engine, left_expr: str, right_expr: str,
             for j, f in enumerate(ra):
                 rcols[j, :nr] = ra[f]
             with jax.enable_x64(True):
-                lo, hi, order = _jit_join_bounds(lt_pad, rt_pad, t)
+                lt_dev, rt_dev = to_device(lt_pad), to_device(rt_pad)
+                lo, hi, order = _jit_join_bounds(lt_dev, rt_dev, t)
+                trace.device_wait((lo, hi))
                 # zero-copy np views + numpy slicing (eager jax host
                 # slices cost ~0.5ms/op in dispatch)
                 lo_np = np.asarray(lo)[:nl]
@@ -538,9 +547,10 @@ def _compile_join(engine, left_expr: str, right_expr: str,
                     dt = np.zeros(0, np.float64)
                 else:
                     l_dev, r_dev, dt_dev = _jit_join_gather(
-                        lcols, rcols, lt_pad, rt_pad,
-                        jnp.asarray(lo_np), jnp.asarray(cum), order,
+                        to_device(lcols), to_device(rcols), lt_dev, rt_dev,
+                        to_device(lo_np), to_device(cum), order,
                         pairs=_pow2(pairs))
+                    trace.device_wait((l_dev, r_dev, dt_dev))
                     l_out = np.asarray(l_dev)[:, :pairs]
                     r_out = np.asarray(r_dev)[:, :pairs]
                     dt = np.asarray(dt_dev)[:pairs]
@@ -554,10 +564,10 @@ def _compile_join(engine, left_expr: str, right_expr: str,
                         "interval joins executed", kind="full").inc()
         cols = {}
         for j, f in enumerate(la):
-            cols[f"l_{f}"] = jnp.asarray(l_out[j])
+            cols[f"l_{f}"] = to_device(l_out[j])
         for j, f in enumerate(ra):
-            cols[f"r_{f}"] = jnp.asarray(r_out[j])
-        cols["dt"] = jnp.asarray(dt)
+            cols[f"r_{f}"] = to_device(r_out[j])
+        cols["dt"] = to_device(dt)
         return dm.Table(cols)
 
     return CompiledStreamQuery("join", run)

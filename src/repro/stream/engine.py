@@ -74,7 +74,7 @@ import numpy as np
 from repro.core import datamodel as dm
 from repro.core.engines import ENGINE_KINDS, Engine
 from repro.core.executor import DataUnavailableException
-from repro.obs import trace
+from repro.obs import metrics, trace
 
 # reserved per-row field carrying the logical stream's global sequence
 # number inside shard ring buffers (float64 is exact for seq < 2**53)
@@ -83,6 +83,17 @@ SEQ_FIELD = "__seq"
 # aggregates that decompose into per-shard partials / rolling sums
 _ROLLING_AGGS = ("count", "sum", "avg")
 _COMBINABLE_AGGS = _ROLLING_AGGS + ("min", "max")
+
+
+def to_device(values) -> jnp.ndarray:
+    """``jnp.asarray(values)``, counting the bytes that land on the
+    device in ``repro_stream_h2d_bytes_total``: every host array a
+    stream query hands to the device goes through here."""
+    arr = jnp.asarray(values)
+    metrics.counter("repro_stream_h2d_bytes_total",
+                    "bytes the stream path copied from host arrays to "
+                    "the device").inc(arr.nbytes)
+    return arr
 
 
 def _memoized_window_aggregate(stream, size: int, fn: str, field: str,
@@ -809,10 +820,10 @@ class Stream(_MultiProducerIngest):
     def snapshot(self) -> dm.Table:
         with self._lock:
             first_seq = self.total_appended - self._count
-            cols = {"seq": jnp.asarray(
+            cols = {"seq": to_device(
                 first_seq + np.arange(self._count))}
             for f in self.fields:
-                cols[f] = jnp.asarray(self._ordered(f))
+                cols[f] = to_device(self._ordered(f))
             return dm.Table(cols)
 
     def window(self, size: int,
@@ -834,7 +845,7 @@ class Stream(_MultiProducerIngest):
                         f"stream {self.name!r}: window [{s},{s + size}) "
                         f"already evicted (buffer starts at {first_seq})")
                 off = s - first_seq
-                attrs = {f: jnp.asarray(self._ordered(f)[off:off + size])
+                attrs = {f: to_device(self._ordered(f)[off:off + size])
                          for f in self.fields}
                 return dm.ArrayObject(attrs, ("tick",))
             assert slide > 0
@@ -846,7 +857,7 @@ class Stream(_MultiProducerIngest):
             attrs = {}
             for f in self.fields:
                 buf = self._ordered(f)
-                attrs[f] = jnp.asarray(
+                attrs[f] = to_device(
                     np.stack([buf[s:s + size] for s in starts]))
             return dm.ArrayObject(attrs, ("window", "tick"))
 
@@ -875,7 +886,7 @@ class Stream(_MultiProducerIngest):
                     f"{self._evicted_ts} overwritten)")
             a, b = self._seq_bounds_locked(self.ts_field, start, end)
             idx = (self._pos(0) + np.arange(a, b)) % self.capacity
-            attrs = {f: jnp.asarray(self._cols[f][idx])
+            attrs = {f: to_device(self._cols[f][idx])
                      for f in self.fields}
             return dm.ArrayObject(attrs, ("tick",))
 
@@ -1758,7 +1769,7 @@ class ShardedStream(_MultiProducerIngest):
                     f"already evicted (rows up to ts {evicted} "
                     f"overwritten)")
             _, cols = self._gather_field_range(self.ts_field, start, end)
-            attrs = {f: jnp.asarray(cols[f]) for f in self.fields}
+            attrs = {f: to_device(cols[f]) for f in self.fields}
             return dm.ArrayObject(attrs, ("tick",))
 
     # -- reads: seq-ordered gather --------------------------------------------
@@ -1788,21 +1799,24 @@ class ShardedStream(_MultiProducerIngest):
         punch holes below the frontier mid-read either."""
         frontier = self.total_appended if upto is None else int(upto)
         seq_parts, col_parts = [], {f: [] for f in self.fields}
-        with self._all_shard_locks():
-            for shard in self._shards:
-                seq_parts.append(shard._ordered(SEQ_FIELD))
-                for f in self.fields:
-                    col_parts[f].append(shard._ordered(f))
-        seqs = np.concatenate(seq_parts) if seq_parts else \
-            np.zeros(0, np.float64)
-        cols = {f: np.concatenate(v) if v else np.zeros(0, np.float64)
-                for f, v in col_parts.items()}
-        keep = seqs < frontier
-        if not keep.all():
-            seqs = seqs[keep]
-            cols = {f: v[keep] for f, v in cols.items()}
-        order = np.argsort(seqs, kind="stable")
-        return seqs[order], {f: v[order] for f, v in cols.items()}
+        with trace.span("stream/gather", stream=self.name,
+                        path="interpreted") as sp:
+            with self._all_shard_locks():
+                for shard in self._shards:
+                    seq_parts.append(shard._ordered(SEQ_FIELD))
+                    for f in self.fields:
+                        col_parts[f].append(shard._ordered(f))
+            seqs = np.concatenate(seq_parts) if seq_parts else \
+                np.zeros(0, np.float64)
+            cols = {f: np.concatenate(v) if v else np.zeros(0, np.float64)
+                    for f, v in col_parts.items()}
+            keep = seqs < frontier
+            if not keep.all():
+                seqs = seqs[keep]
+                cols = {f: v[keep] for f, v in cols.items()}
+            order = np.argsort(seqs, kind="stable")
+            sp.set(rows=int(order.shape[0]))
+            return seqs[order], {f: v[order] for f, v in cols.items()}
 
     def _gather_range(self, s: int, e: int
                       ) -> Tuple[np.ndarray, Dict[str, np.ndarray]]:
@@ -1821,30 +1835,35 @@ class ShardedStream(_MultiProducerIngest):
         lock; all shard locks are held across the sweep, so the slices
         are one point-in-time cut."""
         seq_parts, col_parts = [], {f: [] for f in self.fields}
-        with self._all_shard_locks():
-            for shard in self._shards:
-                a, b = shard._seq_bounds_locked(field, float(lo),
-                                                float(hi))
-                if b <= a:
-                    continue
-                idx = (shard._pos(0) + np.arange(a, b)) % shard.capacity
-                seq_parts.append(shard._cols[SEQ_FIELD][idx])
-                for f in self.fields:
-                    col_parts[f].append(shard._cols[f][idx])
-        if not seq_parts:
-            return np.zeros(0, np.float64), {f: np.zeros(0, np.float64)
-                                             for f in self.fields}
-        seqs = np.concatenate(seq_parts)
-        order = np.argsort(seqs, kind="stable")
-        return seqs[order], {f: np.concatenate(v)[order]
-                             for f, v in col_parts.items()}
+        with trace.span("stream/gather", stream=self.name,
+                        path="interpreted") as sp:
+            with self._all_shard_locks():
+                for shard in self._shards:
+                    a, b = shard._seq_bounds_locked(field, float(lo),
+                                                    float(hi))
+                    if b <= a:
+                        continue
+                    idx = (shard._pos(0) + np.arange(a, b)) \
+                        % shard.capacity
+                    seq_parts.append(shard._cols[SEQ_FIELD][idx])
+                    for f in self.fields:
+                        col_parts[f].append(shard._cols[f][idx])
+            if not seq_parts:
+                sp.set(rows=0)
+                return np.zeros(0, np.float64), {
+                    f: np.zeros(0, np.float64) for f in self.fields}
+            seqs = np.concatenate(seq_parts)
+            order = np.argsort(seqs, kind="stable")
+            sp.set(rows=int(order.shape[0]))
+            return seqs[order], {f: np.concatenate(v)[order]
+                                 for f, v in col_parts.items()}
 
     def snapshot(self) -> dm.Table:
         with self._lock:
             seqs, cols = self._gather()
-            out = {"seq": jnp.asarray(seqs.astype(np.int64))}
+            out = {"seq": to_device(seqs.astype(np.int64))}
             for f in self.fields:
-                out[f] = jnp.asarray(cols[f])
+                out[f] = to_device(cols[f])
             return dm.Table(out)
 
     def window(self, size: int,
@@ -1867,7 +1886,7 @@ class ShardedStream(_MultiProducerIngest):
                         f"stream {self.name!r}: window [{s},{s + size}) "
                         f"already evicted (shards retain "
                         f"{seqs.shape[0]}/{size} rows)")
-                attrs = {f: jnp.asarray(cols[f])
+                attrs = {f: to_device(cols[f])
                          for f in self.fields}
                 return dm.ArrayObject(attrs, ("tick",))
             assert slide > 0
@@ -1887,7 +1906,7 @@ class ShardedStream(_MultiProducerIngest):
             attrs = {}
             for f in self.fields:
                 buf = cols[f][a:]
-                attrs[f] = jnp.asarray(
+                attrs[f] = to_device(
                     np.stack([buf[s0:s0 + size] for s0 in starts]))
             return dm.ArrayObject(attrs, ("window", "tick"))
 

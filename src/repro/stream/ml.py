@@ -52,7 +52,6 @@ from __future__ import annotations
 
 import dataclasses
 import re
-import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -313,15 +312,10 @@ def execute_ml(engine: Engine, query: str) -> dm.Table:
         windows, n = _window_values(engine, window_expr, kwargs.get("field"))
         scores, rows = [], []
         for i, vals in enumerate(windows):
-            t0 = time.perf_counter()
             with trace.span("ml/score", model=handle.arch, window=i,
                             rows=int(vals.shape[0])):
                 toks = quantize(vals, loaded.cfg.vocab_size)
-                scores.append(score_tokens(loaded, toks))
-            metrics.histogram("repro_ml_score_seconds",
-                              "per-window forward + NLL time",
-                              model=handle.arch).observe(
-                time.perf_counter() - t0)
+                scores.append(trace.device_wait(score_tokens(loaded, toks)))
         _STATS["windows_scored"] += n
         metrics.counter("repro_ml_windows_scored_total",
                         "windows scored").inc(n)

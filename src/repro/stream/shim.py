@@ -63,7 +63,7 @@ from repro.core import datamodel as dm
 from repro.core.engines import Engine
 from repro.obs import metrics
 from repro.stream.engine import (_COMBINABLE_AGGS, ShardedStream, Stream,
-                                 StreamException)
+                                 StreamException, to_device)
 
 _AGG_RE = re.compile(r"^(count|sum|avg|min|max)\(\s*(\*|[\w\.]+)\s*\)$",
                      re.IGNORECASE)
@@ -161,7 +161,7 @@ def interval_join(left: dm.ArrayObject, right: dm.ArrayObject,
     for f, v in ra.items():
         cols[f"r_{f}"] = v[ri]
     cols["dt"] = ra[on][ri] - la[on][li]
-    return dm.Table({k: jnp.asarray(v) for k, v in cols.items()})
+    return dm.Table({k: to_device(v) for k, v in cols.items()})
 
 
 def _as_window(value) -> dm.ArrayObject:
@@ -357,7 +357,7 @@ def execute_stream(engine: Engine, query: str):
                 value = stream.window_aggregate(int(win.group(2)),
                                                 agg_fn, target)
                 return dm.ArrayObject(
-                    {f"{agg_fn}_{target}": jnp.asarray([value])}, ("i",))
+                    {f"{agg_fn}_{target}": to_device([value])}, ("i",))
         value = execute_stream(engine, args[0])
         if isinstance(value, dm.Table):
             value = dm.ArrayObject(

@@ -158,6 +158,138 @@ def test_flamegraph_shows_paths_and_counts(traced):
     assert re.search(r"\s3\s", row)           # call count aggregated
 
 
+def _rec(name, span_id, parent_id, start, duration, thread_id=1):
+    return trace.SpanRecord(name=name, trace_id="t", span_id=span_id,
+                            parent_id=parent_id, start=start,
+                            duration=duration, thread_id=thread_id,
+                            thread_name=f"th{thread_id}")
+
+
+def test_self_times_clip_and_merge_children_across_threads():
+    recs = [_rec("stream/tick", 1, None, 0.0, 10.0),
+            _rec("stream/query", 2, 1, 1.0, 3.0),
+            _rec("planner/query", 3, 2, 2.0, 1.0),
+            # a pool worker's child overlapping its sibling on another
+            # thread: the overlap [3, 4) is not taken twice
+            _rec("executor/node", 4, 1, 3.0, 3.0, thread_id=2),
+            # a child outliving its parent is clipped to [8, 10)
+            _rec("serve/fanout", 5, 1, 8.0, 4.0),
+            _rec("stream/gather", 6, None, 20.0, 2.0)]
+    own = trace.self_times(recs)
+    # tick: 10 - |[1, 6) u [8, 10)| = 3; query: 3 - 1; leaves keep all
+    assert own == pytest.approx({1: 3.0, 2: 2.0, 3: 1.0, 4: 3.0, 5: 4.0,
+                                 6: 2.0})
+    text = trace.flamegraph(recs)
+    assert text.splitlines()[0].split()[:3] == ["total_ms", "self_ms",
+                                                "calls"]
+    row = next(ln for ln in text.splitlines() if "stream/tick" in ln)
+    assert row.split()[:3] == ["10000.00", "3000.00", "1"]
+
+
+@pytest.mark.parametrize("on", [True, False])
+def test_program_spans_are_profiler_host_events(on, tmp_path):
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    names = ("stream/tick", "stream/gather", "planner/query",
+             "executor/node", "device/wait")
+    f = jax.jit(lambda x: x @ x)
+    x = jnp.ones((32, 32))
+    f(x).block_until_ready()
+    prev = trace.set_enabled(on)
+    trace.reset()
+    try:
+        jax.profiler.start_trace(str(tmp_path))
+        with trace.span("stream/tick", tick=3):
+            with trace.span("stream/gather", rows=32):
+                pass
+            with trace.span("planner/query"):
+                with trace.span("executor/node"):
+                    trace.device_wait(f(x))
+        jax.profiler.stop_trace()
+    finally:
+        trace.set_enabled(prev)
+        trace.reset()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                            "*.xplane.pb"))
+    host = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name in names:
+                        host[e.name] = e
+    if not on:
+        assert host == {}
+        return
+    assert set(host) == set(names)
+    tick = host["stream/tick"]
+    assert dict(tick.stats)["step_num"] == 3
+    # one clock: every child lies inside the tick
+    for e in host.values():
+        assert tick.start_ns <= e.start_ns
+        assert e.start_ns + e.duration_ns <= \
+            tick.start_ns + tick.duration_ns
+
+
+@pytest.mark.parametrize("on", [True, False])
+def test_device_wait_spans_only_under_tracing(on):
+    import jax.numpy as jnp
+
+    from repro.core import datamodel as dm
+
+    prev = trace.set_enabled(on)
+    trace.reset()
+    try:
+        value = dm.ArrayObject({"a": jnp.arange(4.0) * 2}, ("tick",))
+        assert trace.device_wait(value) is value
+        arr = jnp.ones(3) + 1
+        assert trace.device_wait(arr) is arr
+        waits = [r for r in trace.spans() if r.name == "device/wait"]
+    finally:
+        trace.set_enabled(prev)
+        trace.reset()
+    assert len(waits) == (2 if on else 0)
+
+
+@pytest.mark.parametrize("shards,backend,path", [
+    (2, "interpreter", "interpreted"), (1, "jit", "compiled")])
+def test_window_tick_records_gather_and_h2d_bytes(traced, registry,
+                                                  monkeypatch, shards,
+                                                  backend, path):
+    from repro.stream.spec import Sharding, StreamSpec
+
+    monkeypatch.setenv("REPRO_QUERY_BACKEND", backend)
+    cap, size, fields = 256, 32, ("hr", "bp")
+    bd = default_deployment()
+    stream = bd.register_stream("streamstore0", StreamSpec(
+        "vitals.stream", fields, capacity=cap,
+        sharding=Sharding(shards=shards) if shards > 1 else None))
+    stream.append({f: np.arange(100.0) for f in fields})
+    bd.register_continuous(f"bdstream(window(vitals.stream, {size}))",
+                           every_n_ticks=1, name="w")
+    trace.reset()
+    before = metrics.counter("repro_stream_h2d_bytes_total").value
+    bd.streams.tick()
+    moved = metrics.counter("repro_stream_h2d_bytes_total").value - before
+    gathers = [r for r in trace.spans() if r.name == "stream/gather"]
+    assert [g.attrs["path"] for g in gathers] == [path]
+    # float32 window columns on the device; the compiled plan also
+    # hands over the ring stacked oldest-first in float64
+    window = size * len(fields) * 4
+    if path == "interpreted":
+        assert gathers[0].attrs["rows"] == size
+        assert moved == window
+    else:
+        assert gathers[0].attrs["rows"] == 100
+        assert moved == window + cap * len(fields) * 8
+    tick = next(r for r in trace.spans() if r.name == "stream/tick")
+    assert gathers[0].trace_id == tick.trace_id
+
+
 def test_slow_op_threshold_with_injected_clock(traced, monkeypatch):
     ticks = iter([0.0, 0.050, 1.0, 1.250])    # 50 ms span, then 250 ms
     monkeypatch.setattr(trace, "_clock", lambda: next(ticks))
