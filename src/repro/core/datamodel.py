@@ -8,11 +8,23 @@ array model backs tensor state, and the KV model backs the serving cache.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+import functools
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
+
+from repro.obs import metrics
+
+# the comparisons a filter condition may make (relational and array
+# islands alike): op -> fn(field, value)
+OPS = {
+    ">=": lambda a, b: a >= b, "<=": lambda a, b: a <= b,
+    "!=": lambda a, b: a != b, "=": lambda a, b: a == b,
+    ">": lambda a, b: a > b, "<": lambda a, b: a < b,
+}
 
 
 # ---------------------------------------------------------------------------
@@ -96,6 +108,9 @@ class ArrayObject:
     attrs: Dict[str, jax.Array]            # name -> array of shape dims_shape
     dim_names: Tuple[str, ...]
     valid: Optional[jax.Array] = None      # bool mask (sparse-cell emulation)
+    # filter conditions not yet applied to ``valid``: (attribute or
+    # dimension name, op of OPS, value); ``mask()`` applies them
+    conds: Tuple[Tuple[str, str, Any], ...] = ()
 
     @property
     def shape(self) -> Tuple[int, ...]:
@@ -105,9 +120,19 @@ class ArrayObject:
         return int(sum(np.asarray(v).nbytes for v in self.attrs.values()))
 
     def mask(self) -> jax.Array:
-        if self.valid is None:
-            return jnp.ones(self.shape, bool)
-        return self.valid
+        """The selected cells: ``valid`` (every cell where None) and
+        each pending condition."""
+        m = self.valid
+        for lhs, op, value in self.conds:
+            field = self.attrs[lhs] if lhs in self.attrs \
+                else self.dim_grid(lhs)
+            hit = OPS[op](field, value)
+            m = hit if m is None else m & hit
+        return jnp.ones(self.shape, bool) if m is None else m
+
+    def _selection(self) -> Optional[jax.Array]:
+        """``valid`` with the pending conditions applied."""
+        return self.mask() if self.conds else self.valid
 
     def dim_grid(self, name: str) -> jax.Array:
         axis = self.dim_names.index(name)
@@ -119,43 +144,69 @@ class ArrayObject:
 
     def project(self, names: Sequence[str]) -> "ArrayObject":
         return ArrayObject({n: self.attrs[n] for n in names},
-                           self.dim_names, self.valid)
+                           self.dim_names, self._selection())
 
-    def filter(self, pred: Callable[["ArrayObject"], jax.Array]
-               ) -> "ArrayObject":
-        new_mask = self.mask() & pred(self)
-        return ArrayObject(dict(self.attrs), self.dim_names, new_mask)
+    def filter(self, lhs: str, op: str, value: Any) -> "ArrayObject":
+        """Keep the cells where ``lhs op value`` (``lhs`` an attribute
+        or a dimension), as a pending condition: the mask is built only
+        when ``mask()`` is asked for, and ``aggregate`` evaluates it
+        inside its reduction."""
+        if lhs not in self.attrs and lhs not in self.dim_names:
+            raise ValueError(f"unknown attr/dim {lhs!r}")
+        return ArrayObject(dict(self.attrs), self.dim_names, self.valid,
+                           self.conds + ((lhs, op, value),))
 
     def aggregate(self, agg: str, attr: str) -> "ArrayObject":
-        v = self.attrs[attr]
-        m = self.mask()
-        cnt = jnp.maximum(m.sum(), 1)
-        if agg == "count":
-            out = m.sum()
-        elif agg == "sum":
-            out = jnp.where(m, v, 0).sum()
-        elif agg == "avg":
-            out = jnp.where(m, v, 0).sum() / cnt
-        elif agg == "min":
-            out = jnp.where(m, v, jnp.inf).min()
-        elif agg == "max":
-            out = jnp.where(m, v, -jnp.inf).max()
-        else:
+        if agg not in _AGGS:
             raise ValueError(agg)
+        if self.conds:
+            out = self._fused_aggregate(agg, attr)
+        else:
+            if self.valid is not None:
+                metrics.counter(
+                    "repro_array_masked_aggregates_total",
+                    "array aggregates over a mask already built").inc()
+            out = _reduce(agg, self.attrs[attr], self.mask())
         return ArrayObject({f"{agg}_{attr}": out[None]}, ("i",))
+
+    def _fused_aggregate(self, agg: str, attr: str) -> jax.Array:
+        """``aggregate`` over the pending conditions in one jitted
+        program: the thresholds are its arguments, so conditions that
+        differ only in value share one executable, and each is compared
+        in the dtype the eager comparison would promote to."""
+        cols = [attr]
+        tests, values = [], []
+        for lhs, op, value in self.conds:
+            if lhs in self.attrs:
+                if lhs not in cols:
+                    cols.append(lhs)
+                kind, at = "attr", cols.index(lhs)
+                dtype = self.attrs[lhs].dtype
+            else:
+                kind, at = "dim", self.dim_names.index(lhs)
+                dtype = jax.dtypes.canonicalize_dtype(jnp.int_)
+            tests.append((op, kind, at))
+            values.append(np.asarray(value, jnp.result_type(dtype, value)))
+        metrics.counter("repro_array_fused_aggregates_total",
+                        "array filter+aggregates run as one fused "
+                        "program").inc()
+        return _filter_reduce(agg, tuple(tests),
+                              tuple(self.attrs[n] for n in cols),
+                              self.valid, tuple(values))
 
     def redimension(self, new_shape: Tuple[int, ...],
                     new_dims: Tuple[str, ...]) -> "ArrayObject":
         attrs = {n: v.reshape(new_shape) for n, v in self.attrs.items()}
-        valid = None if self.valid is None else self.valid.reshape(new_shape)
+        valid = self._selection()
+        valid = None if valid is None else valid.reshape(new_shape)
         return ArrayObject(attrs, new_dims, valid)
 
     def sort(self, attr: str) -> "ArrayObject":
         flat = self.attrs[attr].reshape(-1)
         order = jnp.argsort(flat)
         attrs = {n: v.reshape(-1)[order] for n, v in self.attrs.items()}
-        valid = None if self.valid is None \
-            else self.valid.reshape(-1)[order]
+        valid = self._selection()
+        valid = None if valid is None else valid.reshape(-1)[order]
         return ArrayObject(attrs, ("i",), valid)
 
     def cross_join(self, other: "ArrayObject") -> "ArrayObject":
@@ -168,6 +219,59 @@ class ArrayObject:
         for n, v in b.items():
             out[n if n not in out else f"r_{n}"] = jnp.tile(v, na)
         return ArrayObject(out, ("i",))
+
+
+_AGGS = ("count", "sum", "avg", "min", "max")
+
+
+def _reduce(agg: str, v: jax.Array, m: jax.Array) -> jax.Array:
+    """``agg`` of ``v`` over the cells where ``m``; an empty selection
+    counts 0, averages 0 and gives -inf / +inf as its max / min."""
+    cnt = jnp.maximum(m.sum(), 1)
+    if agg == "count":
+        return m.sum()
+    if agg == "sum":
+        return jnp.where(m, v, 0).sum()
+    if agg == "avg":
+        return jnp.where(m, v, 0).sum() / cnt
+    if agg == "min":
+        return jnp.where(m, v, jnp.inf).min()
+    return jnp.where(m, v, -jnp.inf).max()
+
+
+@functools.partial(jax.jit, static_argnames=("agg", "tests"))
+def _filter_reduce(agg: str, tests: Tuple[Tuple[str, str, int], ...],
+                   cols: Tuple[jax.Array, ...], valid: Optional[jax.Array],
+                   values: Tuple[jax.Array, ...]) -> jax.Array:
+    """``agg`` of ``cols[0]`` over ``valid`` and the conditions
+    ``tests``: (op, "attr", index into ``cols``) or (op, "dim", axis),
+    each against its value.  XLA fuses the comparisons, the select and
+    the reduction into one read of the inputs; no mask is stored."""
+    v = cols[0]
+    m = valid
+    for (op, kind, at), value in zip(tests, values):
+        field = cols[at] if kind == "attr" else lax.broadcasted_iota(
+            jax.dtypes.canonicalize_dtype(jnp.int_), v.shape, at)
+        hit = OPS[op](field, value)
+        m = hit if m is None else m & hit
+    return _masked_mean(v, m) if agg == "avg" else _reduce(agg, v, m)
+
+
+def _masked_mean(v: jax.Array, m: jax.Array) -> jax.Array:
+    """``_reduce("avg", v, m)`` with the sum and the count as one
+    variadic reduction, so the two read ``v`` once (as two reductions
+    XLA reads it twice).  Sum and count keep ``_reduce``'s dtypes; a
+    float sum accumulates in float32 or wider."""
+    s = jnp.where(m, v, 0)
+    sum_dtype = jax.eval_shape(jnp.sum, s).dtype
+    acc = jnp.promote_types(sum_dtype, jnp.float32) \
+        if jnp.issubdtype(sum_dtype, jnp.floating) else sum_dtype
+    cnt_dtype = jax.eval_shape(jnp.sum, m).dtype
+    total, n = lax.reduce(
+        (s.astype(acc), m.astype(cnt_dtype)),
+        (np.zeros((), acc), np.zeros((), cnt_dtype)),
+        lambda a, b: (a[0] + b[0], a[1] + b[1]), tuple(range(v.ndim)))
+    return total.astype(sum_dtype) / jnp.maximum(n, 1)
 
 
 # ---------------------------------------------------------------------------

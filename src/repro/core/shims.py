@@ -22,12 +22,6 @@ import numpy as np
 from repro.core import datamodel as dm
 from repro.core.engines import Engine
 
-_OPS = {
-    ">=": lambda a, b: a >= b, "<=": lambda a, b: a <= b,
-    "!=": lambda a, b: a != b, "=": lambda a, b: a == b,
-    ">": lambda a, b: a > b, "<": lambda a, b: a < b,
-}
-
 
 def _parse_value(tok: str):
     tok = tok.strip().strip("'\"")
@@ -115,7 +109,7 @@ def execute_relational(engine: Engine, sql: str) -> dm.Table:
 
     for col, op, val in filters:
         c = _strip_prefix(col, table)
-        mask = _OPS[op](table.columns[c], val)
+        mask = dm.OPS[op](table.columns[c], val)
         table = table.filter(mask)
 
     group = m.group("group")
@@ -200,7 +194,7 @@ def execute_afl(engine: Engine, afl: str) -> dm.ArrayObject:
         return execute_afl(engine, args[0])
     if fn == "filter":
         arr = execute_afl(engine, args[0])
-        return arr.filter(lambda a: _afl_condition(a, args[1]))
+        return arr.filter(*_afl_condition(args[1]))
     if fn == "project":
         arr = execute_afl(engine, args[0])
         return arr.project([a.strip() for a in args[1:]])
@@ -267,19 +261,12 @@ def _split_args(s: str) -> List[str]:
     return parts
 
 
-def _afl_condition(arr: dm.ArrayObject, cond: str):
+def _afl_condition(cond: str) -> Tuple[str, str, Any]:
+    """``'signal > 0.5'`` -> ``('signal', '>', 0.5)``."""
     for op in ("<=", ">=", "!=", "=", "<", ">"):
         if op in cond:
             lhs, rhs = cond.split(op, 1)
-            lhs = lhs.strip()
-            val = _parse_value(rhs)
-            if lhs in arr.attrs:
-                field = arr.attrs[lhs]
-            elif lhs in arr.dim_names:
-                field = arr.dim_grid(lhs)
-            else:
-                raise ValueError(f"unknown attr/dim {lhs!r}")
-            return _OPS[op](field, val)
+            return lhs.strip(), op, _parse_value(rhs)
     raise ValueError(f"bad AFL condition: {cond!r}")
 
 

@@ -129,3 +129,19 @@ def test_qwen2_forward_fits_one_chip(one_chip, sizes):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert total < HBM_BYTES, total
+
+
+@pytest.mark.parametrize("agg", ["count", "avg", "max"])
+def test_array_filter_aggregate_reads_the_waveform_once(agg, one_chip):
+    from repro.core import datamodel as dm
+    # the batch waveform: 10 bed-days of 8 leads at 125 Hz
+    wave = jax.ShapeDtypeStruct((80, 10_800_000), jnp.float32,
+                                sharding=one_chip)
+    x = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    compiled = dm._filter_reduce.lower(agg, ((">", "attr", 0),), (wave,),
+                                       None, (x,)).compile()
+    mem = compiled.memory_analysis()
+    # a mask (a quarter of the input) or a selected copy (all of it)
+    # would be a temporary of the input's order
+    assert mem.temp_size_in_bytes < mem.argument_size_in_bytes / 1000
+    assert compiled.as_text().count(" fusion(") == 1
