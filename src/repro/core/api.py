@@ -344,7 +344,8 @@ class BigDawg:
         return sorted(e for e in self.engines if e.startswith("mlhost"))
 
     def register_model(self, alias: str, arch: Optional[str] = None,
-                       engine_name: str = "mlhost0", seed: int = 0):
+                       engine_name: str = "mlhost0", seed: int = 0,
+                       params=None):
         """Register a model handle on the ml island so ``bdml`` queries
         can score stream windows through it:
 
@@ -360,8 +361,12 @@ class BigDawg:
         infer reads to the model's home engine.  Params are derived
         from a fixed seed at first use and cached per (arch, seed,
         reduced), so every deployment (sharded, replayed, front-door)
-        scores with bit-identical weights."""
-        from repro.stream.ml import ALIASES, MLModel, resolve_arch
+        scores with bit-identical weights.  ``params`` (the arch's param
+        tree) are served instead of the seed's draw: they are installed
+        in that cache under the handle's (arch, seed, reduced) at once
+        (``repro.stream.ml.load_model``)."""
+        from repro.stream.ml import ALIASES, MLModel, load_model, \
+            resolve_arch
         self.ensure_ml_engines(
             max(1, int(engine_name[len("mlhost"):]) + 1)
             if engine_name.startswith("mlhost")
@@ -370,6 +375,8 @@ class BigDawg:
         handle = MLModel(name=f"models.{alias}", arch=resolve_arch(name),
                          seed=seed, home_engine=engine_name,
                          reduced=name in ALIASES)
+        if params is not None:
+            load_model(handle.arch, seed, handle.reduced, params=params)
         self.register_object(engine_name, handle.name, handle,
                              fields=("window", "rows", "score"))
         return handle

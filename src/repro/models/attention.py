@@ -49,7 +49,7 @@ def _rms(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
 
 
 def project_qkv(params: dict, x: jax.Array, cfg: ModelConfig, rules,
-                positions: Optional[jax.Array], *, use_rope: bool = True,
+                positions: Optional[jax.Array]
                 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     dt = x.dtype
     q = jnp.einsum("bsd,dhk->bshk", x, params["wq"].astype(dt))
@@ -62,7 +62,7 @@ def project_qkv(params: dict, x: jax.Array, cfg: ModelConfig, rules,
     if cfg.qk_norm:
         q = _rms(q, params["q_norm"], cfg.norm_eps)
         k = _rms(k, params["k_norm"], cfg.norm_eps)
-    if use_rope and positions is not None:
+    if cfg.uses_rope and positions is not None:
         q = layers.apply_rope(q, positions, cfg.rope_theta)
         k = layers.apply_rope(k, positions, cfg.rope_theta)
     q = L.constrain(q, rules, (L.BATCH, L.SEQ, L.HEADS, L.HEAD_DIM))

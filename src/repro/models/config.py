@@ -101,6 +101,14 @@ class ModelConfig:
         return self.ssm_kind != "" and self.attn_every == 0
 
     @property
+    def uses_rope(self) -> bool:
+        """Attention rotates q and k (RoPE), except in the Jamba family
+        (Mamba mixers beside attention): there the Mamba layers carry
+        position and attention has no positional encoding
+        (arXiv:2403.19887)."""
+        return self.ssm_kind != "mamba"
+
+    @property
     def supports_long_context(self) -> bool:
         """Sub-quadratic decode: SSM and hybrid archs only (DESIGN.md §4)."""
         return self.ssm_kind != ""

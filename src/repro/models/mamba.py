@@ -33,8 +33,8 @@ def mamba_specs(cfg: ModelConfig) -> dict:
         "conv_b": ParamSpec((di,), (L.MLP,), init="zeros"),
         "x_proj": ParamSpec((di, r + 2 * n), (L.MLP, None)),
         "dt_proj": ParamSpec((r, di), (None, L.MLP)),
-        "dt_bias": ParamSpec((di,), (L.MLP,), init="zeros"),
-        "a_log": ParamSpec((di, n), (L.MLP, L.STATE), init="zeros"),
+        "dt_bias": ParamSpec((di,), (L.MLP,), init="ssm_dt_bias"),
+        "a_log": ParamSpec((di, n), (L.MLP, L.STATE), init="ssm_a_log"),
         "d_skip": ParamSpec((di,), (L.MLP,), init="ones"),
         "out_proj": ParamSpec((di, d), (L.MLP, L.EMBED)),
         # Jamba stabilizes dt/B/C with RMSNorm scales

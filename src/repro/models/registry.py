@@ -31,6 +31,7 @@ _ARCH_MODULES = {
     "seamless-m4t-medium": "repro.configs.seamless_m4t_medium",
     "rwkv6-7b": "repro.configs.rwkv6_7b",
     "jamba-v0.1-52b": "repro.configs.jamba_v0_1_52b",
+    "jamba2-3b": "repro.configs.jamba2_3b",
 }
 
 ARCH_NAMES = tuple(_ARCH_MODULES)

@@ -8,6 +8,7 @@ The catalog's engine assignment for an object resolves to a rules table.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Callable, Mapping, Optional, Sequence, Tuple, Union
 
 import jax
@@ -127,7 +128,8 @@ class ParamSpec:
     shape: Tuple[int, ...]
     axes: Tuple[Optional[str], ...]
     dtype: Any = jnp.float32
-    init: str = "normal"      # normal | zeros | ones | embed_normal
+    init: str = "normal"      # normal | zeros | ones | embed_normal |
+    #                           ssm_a_log | ssm_dt_bias
     init_scale: float = 1.0
 
     def __post_init__(self):
@@ -188,18 +190,53 @@ def count_params(spec_tree) -> int:
     return total
 
 
+_HEAD_AXES = (HEADS, KV_HEADS, HEAD_DIM)
+
+
+def fan_in(spec: ParamSpec) -> int:
+    """The input width of a ``"normal"`` weight: the product of its input
+    axes.  A projection into heads, ``(d, H, hd)``, reads ``d``; one out
+    of heads, ``(H, hd, d)``, reads ``H * hd``; any other weight reads
+    its second-to-last axis (the leading axis of a 2-D weight, ``d`` of
+    an expert stack ``(E, d, f)``).  A stacked layer axis is not an
+    input."""
+    dims = [(n, ax) for n, ax in zip(spec.shape, spec.axes) if ax != LAYER]
+    if len(dims) < 2:
+        return dims[-1][0] if dims else 1
+    heads = [i for i, (_, ax) in enumerate(dims) if ax in _HEAD_AXES]
+    if heads and heads[-1] == len(dims) - 1:          # into heads
+        return dims[heads[0] - 1][0]
+    if heads and heads[0] == 0:                       # out of heads
+        out = 1
+        for i in heads:
+            out *= dims[i][0]
+        return out
+    return dims[-2][0]
+
+
 def _init_one(key: jax.Array, spec: ParamSpec) -> jax.Array:
     if spec.init == "zeros":
         return jnp.zeros(spec.shape, spec.dtype)
     if spec.init == "ones":
         return jnp.ones(spec.shape, spec.dtype)
     if spec.init == "normal":
-        fan_in = spec.shape[-2] if len(spec.shape) >= 2 else spec.shape[-1]
-        scale = spec.init_scale / max(1.0, float(fan_in)) ** 0.5
+        scale = spec.init_scale / max(1.0, float(fan_in(spec))) ** 0.5
         return (scale * jax.random.normal(key, spec.shape)).astype(spec.dtype)
     if spec.init == "embed_normal":
         return (spec.init_scale * 0.02
                 * jax.random.normal(key, spec.shape)).astype(spec.dtype)
+    # Mamba's selective-scan parameters (arXiv:2312.00752 §3.6): A_n =
+    # -(n + 1) along the state axis (S4D-real), and a dt bias that puts
+    # softplus(bias) log-uniformly in [1e-3, 1e-1]
+    if spec.init == "ssm_a_log":
+        n = spec.shape[-1]
+        return jnp.broadcast_to(jnp.log(jnp.arange(1, n + 1,
+                                                   dtype=jnp.float32)),
+                                spec.shape).astype(spec.dtype)
+    if spec.init == "ssm_dt_bias":
+        lo, hi = math.log(1e-3), math.log(1e-1)
+        dt = jnp.exp(lo + (hi - lo) * jax.random.uniform(key, spec.shape))
+        return (dt + jnp.log(-jnp.expm1(-dt))).astype(spec.dtype)
     raise ValueError(f"unknown init {spec.init}")
 
 

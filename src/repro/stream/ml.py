@@ -14,7 +14,8 @@ per-window min/max binning over the float64 row values — the same rows
 always produce the same tokens, on any shard layout or replay) and run
 through ``registry.forward`` — on the reduced config for the short
 aliases (``lm``/``moe``/``rwkv6``/``mamba``), on the published config
-for an explicit registry arch name; the score is
+for an explicit registry arch name, with its weights held in
+bfloat16 (``weight_specs``); the score is
 the mean next-token NLL in float32 — an anomaly signal: windows the
 model finds unlikely score high.  The result is a relational Table with
 one row per window (``window``/``rows``/``score``), so scores ride the
@@ -37,9 +38,11 @@ Bit-identity contract (the house invariant):
 Execution rides the serve tier's wave model (``TickWaveScheduler``): all
 standing ``infer`` queries that run within one StreamRuntime tick join a
 single wave — N standing queries cost one wave per tick, sharing the
-params/jit caches — with ``ml/wave`` / ``ml/score`` spans and
-``repro_ml_*`` metrics.  ``StreamRuntime.tick`` mirrors ``stats()`` into
-``Monitor.observe_ml`` so ``admin.status()["ml"]`` tracks it live.
+params/jit caches — with ``ml/wave`` / ``ml/score`` (arch, rows) spans
+and ``repro_ml_*`` metrics (``repro_ml_tokens_scored_total`` by arch,
+``repro_ml_param_bytes`` per loaded model).  ``StreamRuntime.tick``
+mirrors ``stats()`` into ``Monitor.observe_ml`` so
+``admin.status()["ml"]`` tracks it live.
 
 Model handles are registered via ``BigDawg.register_model`` on an
 ``MLEngine`` (``bd.ensure_ml_engines``); the Planner pins ``infer``
@@ -83,9 +86,9 @@ class MLException(StreamException):
 
 
 # registry architectures behind the island's short model aliases (there
-# is no pure-mamba arch in the pool; jamba is the mamba-hybrid)
+# is no pure-mamba arch in the pool; jamba2-3b is the mamba-hybrid)
 ALIASES = {"lm": "qwen2-1.5b", "moe": "olmoe-1b-7b",
-           "rwkv6": "rwkv6-7b", "mamba": "jamba-v0.1-52b"}
+           "rwkv6": "rwkv6-7b", "mamba": "jamba2-3b"}
 
 
 def resolve_arch(name: str) -> str:
@@ -155,26 +158,86 @@ def stats() -> Dict[str, Any]:
     return out
 
 
-def load_model(arch: str, seed: int = 0, reduced: bool = True) -> _Loaded:
+def weight_specs(cfg):
+    """The model's ParamSpec tree with every weight held in bfloat16: the
+    dtype each matmul casts its weights to already.  Norm scales (1)
+    stay exact; the scan's ``a_log`` and dt bias are the bfloat16
+    roundings of their draws, as every other weight is."""
+    return jax.tree.map(
+        lambda s: dataclasses.replace(s, dtype=jnp.bfloat16),
+        registry.param_specs(cfg),
+        is_leaf=lambda x: isinstance(x, _logical.ParamSpec))
+
+
+def load_model(arch: str, seed: int = 0, reduced: bool = True,
+               params: Any = None) -> _Loaded:
     """The per-(arch, seed, reduced) params + jitted-forward cache.
     Params are derived from a fixed PRNGKey, so every deployment that
-    registers the same model scores with bit-identical weights."""
+    registers the same model scores with bit-identical weights; they are
+    drawn a leaf at a time and held in bfloat16 (``weight_specs``).
+
+    ``params`` (the registry's param tree of the arch, any float dtype)
+    are served in place of the seed's draw: they replace the key's
+    cache entry, held in bfloat16 like a draw.  A tree of another
+    structure or leaf shape is refused."""
     key = (arch, seed, reduced)
-    if key in _LOADED:
+    if params is None and key in _LOADED:
         _STATS["params_cache_hits"] += 1
         return _LOADED[key]
     cfg = registry.get_config(arch, reduced=reduced)
-    params = _logical.init_params(jax.random.PRNGKey(seed),
-                                  registry.param_specs(cfg))
+    specs = weight_specs(cfg)
+    if params is None:
+        params = _logical.init_params(jax.random.PRNGKey(seed), specs)
+    else:
+        params = _given_params(arch, params, specs)
     fwd = jax.jit(lambda p, toks: registry.forward(
         p, {"tokens": toks}, cfg, None)[0])
     loaded = _Loaded(cfg=cfg, params=params, forward=fwd)
     _LOADED[key] = loaded
     _STATS["models_loaded"] += 1
+    _observe_cache(key, sum(a.nbytes for a in jax.tree.leaves(params)))
+    return loaded
+
+
+def _given_params(arch: str, params: Any, specs: Any) -> Any:
+    """``params`` checked leaf by leaf against ``specs`` and cast to the
+    specs' dtype."""
+    is_spec = lambda x: isinstance(x, _logical.ParamSpec)  # noqa: E731
+    want = jax.tree.structure(specs, is_leaf=is_spec)
+    if jax.tree.structure(params) != want:
+        raise MLException(f"params for {arch!r} do not have the arch's "
+                          f"param tree: got {jax.tree.structure(params)}, "
+                          f"want {want}")
+    bad = [jax.tree_util.keystr(path) for (path, a), s in zip(
+        jax.tree_util.tree_leaves_with_path(params),
+        jax.tree.leaves(specs, is_leaf=is_spec))
+        if tuple(a.shape) != tuple(s.shape)]
+    if bad:
+        raise MLException(f"params for {arch!r}: leaves of the wrong "
+                          f"shape: {bad}")
+    return jax.tree.map(lambda a, s: jnp.asarray(a, s.dtype), params,
+                        specs, is_leaf=is_spec)
+
+
+def unload_model(arch: str, seed: int = 0, reduced: bool = True
+                 ) -> Optional[_Loaded]:
+    """Take one (arch, seed, reduced) entry out of the params cache and
+    return it: its weights are freed once no caller holds them.  The
+    next ``load_model`` of the key draws them again, bit-identically."""
+    key = (arch, seed, reduced)
+    loaded = _LOADED.pop(key, None)
+    _observe_cache(key, 0)
+    return loaded
+
+
+def _observe_cache(key: Tuple[str, int, bool], nbytes: int) -> None:
     metrics.gauge("repro_ml_models_loaded",
                   "(arch, seed) entries in the params cache").set(
         len(_LOADED))
-    return loaded
+    arch, seed, reduced = key
+    metrics.gauge("repro_ml_param_bytes",
+                  "bytes of weights resident per loaded model", arch=arch,
+                  seed=seed, reduced=reduced).set(nbytes)
 
 
 def quantize(values: np.ndarray, vocab: int) -> np.ndarray:
@@ -312,13 +375,17 @@ def execute_ml(engine: Engine, query: str) -> dm.Table:
         windows, n = _window_values(engine, window_expr, kwargs.get("field"))
         scores, rows = [], []
         for i, vals in enumerate(windows):
-            with trace.span("ml/score", model=handle.arch, window=i,
+            with trace.span("ml/score", arch=handle.arch, window=i,
                             rows=int(vals.shape[0])):
                 toks = quantize(vals, loaded.cfg.vocab_size)
                 scores.append(trace.device_wait(score_tokens(loaded, toks)))
         _STATS["windows_scored"] += n
         metrics.counter("repro_ml_windows_scored_total",
                         "windows scored").inc(n)
+        metrics.counter("repro_ml_tokens_scored_total",
+                        "tokens of every scored window",
+                        arch=handle.arch).inc(
+            sum(int(w.shape[0]) for w in windows))
         return dm.Table({
             "window": jnp.arange(n, dtype=jnp.int32),
             "rows": jnp.asarray([w.shape[0] for w in windows], jnp.int32),

@@ -84,6 +84,7 @@ def test_full_config_matches_assignment(name):
         "seamless-m4t-medium": (12, 1024, 16, 16, 4096, 256206),
         "rwkv6-7b": (32, 4096, 64, 64, 14336, 65536),
         "jamba-v0.1-52b": (32, 4096, 32, 8, 14336, 65536),
+        "jamba2-3b": (28, 2560, 20, 1, 8192, 65536),
     }[name]
     got = (cfg.num_layers, cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
            cfg.d_ff, cfg.vocab_size)
@@ -102,9 +103,24 @@ def test_moe_details():
     assert sum(f == "moe" for _, f in jamba.layer_plan()) == 4
 
 
+def test_jamba2_details():
+    """Jamba2-3B: attention at layers 7 and 21 with one KV head and no
+    RoPE, Mamba everywhere else, a dense MLP on every layer."""
+    cfg = registry.get_config("jamba2-3b")
+    kinds = [m for _ in range(cfg.num_scanned())
+             for m, _ in cfg.layer_plan()]
+    assert [i for i, m in enumerate(kinds) if m == "attn"] == [7, 21]
+    assert kinds.count("mamba") == 26
+    assert {f for _, f in cfg.layer_plan()} == {"dense"}
+    assert (cfg.num_kv_heads, cfg.norm_eps, cfg.tie_embeddings) == \
+        (1, 1e-6, True)
+    assert not cfg.uses_rope and registry.get_config("qwen2-1.5b").uses_rope
+    assert L.count_params(registry.param_specs(cfg)) == 3_029_337_472
+
+
 def test_long_context_applicability():
     from repro.configs.shapes import SHAPES, applicable
     long = SHAPES["long_500k"]
     runnable = [n for n in ARCHS
                 if applicable(registry.get_config(n), long)[0]]
-    assert sorted(runnable) == ["jamba-v0.1-52b", "rwkv6-7b"]
+    assert sorted(runnable) == ["jamba-v0.1-52b", "jamba2-3b", "rwkv6-7b"]

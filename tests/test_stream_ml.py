@@ -22,14 +22,20 @@ from repro.stream.spec import Durability, EventTime, Sharding, StreamSpec
 
 ARCH = "qwen2-1.5b"          # the "lm" alias; smallest forward in the pool
 W = 16
+# |island score - float32 reference score| in nats of a 64-token window
+# on the reduced jamba2-3b: the island computes in bfloat16 (2**-8
+# relative per rounding); over six seeds it reads 1.2e-5 to 8.2e-4, so
+# the tolerance is 2.4x its largest reading.  The logits, not this mean,
+# are what separate the float8 control (tests/test_jamba_ref.py).
+SCORE_TOL = 2e-3
 
 
 def direct_score(values, arch=ARCH, seed=0):
     """The reference the island must match bitwise: quantize the rows,
-    run a plain eager ``registry.forward``, mean next-token NLL in f32."""
+    run a plain eager ``registry.forward`` on the island's bfloat16
+    weights, mean next-token NLL in f32."""
     cfg = registry.get_config(arch, reduced=True)
-    params = L.init_params(jax.random.PRNGKey(seed),
-                           registry.param_specs(cfg))
+    params = L.init_params(jax.random.PRNGKey(seed), ml.weight_specs(cfg))
     toks = ml.quantize(np.asarray(values, np.float64), cfg.vocab_size)
     logits, _ = registry.forward(
         params, {"tokens": jnp.asarray(toks[None, :], jnp.int32)}, cfg,
@@ -215,6 +221,64 @@ def test_frontdoor_scored_subscription_matches_direct():
     door.close()
 
 
+def test_frontdoor_scored_bed_matches_the_jamba_reference():
+    """A scored bed on the reduced jamba2-3b (the ``mamba`` alias): both
+    tenants get the shared execution's score, the program's own forward
+    rerun on the window rebuilds it bitwise (rerun mismatch 0), and it
+    lies within the reference tolerance of the float32 reference."""
+    from repro.models import jamba_ref
+    from repro.serve.frontdoor import FrontDoor
+    bd = default_deployment()
+    bd.register_model("scorer", arch="mamba", seed=7)
+    bd.register_stream("streamstore0", StreamSpec(
+        "icu.bed0_abp", ("t", "abp"), capacity=128))
+    door = FrontDoor(bd, stream_engine="streamstore0")
+    q = "bdml(infer(window(icu.bed0_abp, 64), models.scorer, field=abp))"
+    subs = [door.open_session(t).subscribe(q, every_n_ticks=2)
+            for t in ("ward", "cardio")]
+    rng = np.random.default_rng(3)
+    abp = 90 + 12 * np.sin(np.arange(100) / 10) + rng.standard_normal(100)
+    bd.engines["streamstore0"].get("icu.bed0_abp").append(
+        {"t": np.arange(100.0), "abp": abp})
+    assert bd.streams.tick() == [] and all(not s.poll() for s in subs)
+    bd.streams.tick()
+    got = [float(s.poll()[0][1].columns["score"][0]) for s in subs]
+    assert got[0] == got[1] and door.stats()["shared_queries"] == 1
+    loaded = ml.load_model("jamba2-3b", 7, True)
+    # the window as the stream serves it (float32), which the island bins
+    toks = ml.quantize(abp[:64].astype(np.float32), loaded.cfg.vocab_size)
+    assert float(ml.score_tokens(loaded, toks)) == got[0]
+    logits = jamba_ref.forward(loaded.params, toks, loaded.cfg)
+    logp = jax.nn.log_softmax(logits[:-1], -1)
+    want = float(-jnp.take_along_axis(logp, toks[1:, None], -1).mean())
+    assert abs(got[0] - want) < SCORE_TOL, (got[0], want)
+    door.close()
+
+
+def test_ml_counters_and_spans():
+    from repro.obs import metrics, trace
+    bd = _deploy(StreamSpec("vitals.hr", ("ts", "hr"), capacity=64))
+    bd.engines["streamstore0"].get("vitals.hr").append(_rows(2 * W))
+    name = "repro_ml_tokens_scored_total"
+    before = metrics.counter(name, arch=ARCH).value
+    trace.reset()
+    trace.set_enabled(True)
+    try:
+        bd.query(f"bdml(infer(window(vitals.hr, {W}, {W}), models.lm))")
+    finally:
+        trace.set_enabled(False)
+    assert metrics.counter(name, arch=ARCH).value - before == 2 * W
+    spans = [s for s in trace.spans() if s.name == "ml/score"]
+    assert [(s.attrs["arch"], s.attrs["rows"]) for s in spans] == \
+        [(ARCH, W), (ARCH, W)]
+    loaded = ml.load_model(ARCH, 0, True)
+    resident = sum(a.nbytes for a in jax.tree.leaves(loaded.params))
+    assert {a.dtype for a in jax.tree.leaves(loaded.params)} == \
+        {jnp.dtype(jnp.bfloat16)}
+    assert metrics.gauge("repro_ml_param_bytes", arch=ARCH, seed=0,
+                         reduced=True).value == resident
+
+
 # -- failure modes ------------------------------------------------------------
 def test_incomplete_window_is_transient():
     from repro.core.executor import (DataUnavailableException,
@@ -280,3 +344,43 @@ def test_admin_status_and_planner_pinning():
         assert key in st["ml"], key
     assert "mlhost0" in st["islands"]["ml"]
     assert st["engines"]["mlhost0"]["kind"] == "mlserve"
+
+
+def test_unload_model_frees_the_cache_entry():
+    key = ("qwen2-1.5b", 11, True)
+    first = ml.load_model(*key)
+    assert ml.unload_model(*key) is first and key not in ml._LOADED
+    assert ml.unload_model(*key) is None
+    again = ml.load_model(*key)
+    assert again is not first
+    for a, b in zip(jax.tree.leaves(first.params),
+                    jax.tree.leaves(again.params)):
+        assert bool(jnp.array_equal(a, b))
+    ml.unload_model(*key)
+
+
+def test_register_model_serves_the_weights_it_is_handed():
+    """``register_model(params=...)`` installs the given tree (held in
+    bfloat16) under the handle's key in place of the seed's draw, and
+    refuses a tree that is not the arch's."""
+    cfg = registry.get_config(ARCH, reduced=True)
+    specs = ml.weight_specs(cfg)
+    given = jax.tree.map(lambda a: a.astype(jnp.float32),
+                         L.init_params(jax.random.PRNGKey(99), specs))
+    bd = _deploy(StreamSpec("vitals.hr", ("ts", "hr"), capacity=64))
+    bd.register_model("lm", seed=12, params=given)
+    loaded = ml.load_model(ARCH, 12, True)
+    for a, b in zip(jax.tree.leaves(loaded.params), jax.tree.leaves(given)):
+        assert a.dtype == jnp.bfloat16 and bool(jnp.array_equal(a, b))
+    bd.engines["streamstore0"].get("vitals.hr").append(_rows())
+    got = bd.query(f"bdml(infer(window(vitals.hr, {W}), models.lm))").value
+    toks = ml.quantize(_rows()["hr"], cfg.vocab_size)
+    assert float(got.columns["score"][0]) == \
+        float(ml.score_tokens(loaded, toks))
+    ml.unload_model(ARCH, 12, True)
+    wrong = dict(given, final_norm={"scale": jnp.ones((3,))})
+    with pytest.raises(ml.MLException, match="wrong shape"):
+        bd.register_model("lm", seed=13, params=wrong)
+    with pytest.raises(ml.MLException, match="param tree"):
+        bd.register_model("lm", seed=13, params={"embed": given["embed"]})
+    assert (ARCH, 13, True) not in ml._LOADED

@@ -131,6 +131,28 @@ def test_qwen2_forward_fits_one_chip(one_chip, sizes):
     assert total < HBM_BYTES, total
 
 
+def test_jamba2_forward_fits_one_chip(one_chip):
+    """The icu-jamba2 cell's scorer: AI21-Jamba2-3B at published widths,
+    bfloat16 weights as the ml island holds them, one (1, 2048) window."""
+    from repro.models import registry
+    from repro.stream import ml
+    cfg = registry.get_config("jamba2-3b")
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        ml.weight_specs(cfg),
+        is_leaf=lambda x: hasattr(x, "struct"))
+    assert {p.dtype for p in jax.tree.leaves(params)} == \
+        {jnp.dtype(jnp.bfloat16)}
+    toks = jax.ShapeDtypeStruct((1, 2048), jnp.int32, sharding=one_chip)
+    fwd = jax.jit(lambda p, t: registry.forward(p, {"tokens": t}, cfg,
+                                                None)[0])
+    mem = fwd.lower(params, toks).compile().memory_analysis()
+    assert mem.argument_size_in_bytes > 6e9          # the 6.06 GB weights
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < HBM_BYTES, total
+
+
 @pytest.mark.parametrize("agg", ["count", "avg", "max"])
 def test_array_filter_aggregate_reads_the_waveform_once(agg, one_chip):
     from repro.core import datamodel as dm
