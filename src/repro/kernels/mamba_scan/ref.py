@@ -1,4 +1,6 @@
-"""Pure-jnp oracle for the Mamba-1 selective scan (per-step lax.scan)."""
+"""Pure-jnp reference for the Mamba-1 selective scan (per-step lax.scan):
+the model's scan wherever ``ops.uses_kernel`` says no (the CPU, decode at
+S = 1), the kernel's backward, and the tests' oracle."""
 from __future__ import annotations
 
 from typing import Tuple

@@ -1,7 +1,11 @@
 """Mamba (selective SSM) mixer as used in Jamba (arXiv:2403.19887).
 
-Reference implementation scans over time with lax.scan; the chunked Pallas
-kernel lives in kernels/mamba_scan.  Decode is an O(1) state update.
+The selective scan runs through ``kernels/mamba_scan/ops.scan``: the
+lane-dense Pallas kernel when compiled for a TPU over at least one chunk
+of steps and whole 128-lane channel tiles (``ops.uses_kernel``), the
+reference ``lax.scan`` (``kernels/mamba_scan/ref.py``) everywhere else:
+on the CPU, in the tests' interpreter, and in decode at S = 1, an O(1)
+state update.  Gradients through the kernel take the reference's VJP.
 """
 from __future__ import annotations
 
@@ -72,28 +76,6 @@ def _causal_conv(x: jax.Array, w: jax.Array, b: jax.Array,
     return out + b[None, None]
 
 
-def selective_scan(u: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
-                   c: jax.Array, h0: jax.Array
-                   ) -> Tuple[jax.Array, jax.Array]:
-    """u,dt: (B,S,Di); a: (Di,N); b,c: (B,S,N); h0: (B,Di,N) fp32.
-
-      h_t = exp(dt_t ⊙ A) h_{t-1} + (dt_t u_t) ⊗ B_t;  y_t = h_t · C_t
-    """
-    def step(h, inp):
-        ut, dtt, bt, ct = inp                         # (B,Di),(B,Di),(B,N)x2
-        da = jnp.exp(dtt[..., None] * a[None])        # (B,Di,N)
-        h = da * h + (dtt * ut)[..., None] * bt[:, None, :]
-        y = jnp.einsum("bdn,bn->bd", h, ct)
-        return h, y
-
-    xs = (u.swapaxes(0, 1).astype(jnp.float32),
-          dt.swapaxes(0, 1).astype(jnp.float32),
-          b.swapaxes(0, 1).astype(jnp.float32),
-          c.swapaxes(0, 1).astype(jnp.float32))
-    h_final, ys = jax.lax.scan(step, h0, xs)
-    return ys.swapaxes(0, 1), h_final
-
-
 def apply_mamba(params: dict, x: jax.Array, cfg: ModelConfig, rules,
                 state: Optional[dict] = None
                 ) -> Tuple[jax.Array, Optional[dict]]:
@@ -123,7 +105,10 @@ def apply_mamba(params: dict, x: jax.Array, cfg: ModelConfig, rules,
     a = -jnp.exp(params["a_log"].astype(jnp.float32))
     h0 = (state["h"] if state is not None
           else jnp.zeros((bsz, di, n), jnp.float32))
-    y, h_final = selective_scan(xc, dt_full, a, b_in, c_in, h0)
+    # imported here, as attention.py imports its flash kernel: a process
+    # that runs no Mamba forward does not load Pallas
+    from repro.kernels.mamba_scan import ops as scan_ops
+    y, h_final = scan_ops.scan(xc, dt_full, a, b_in, c_in, h0)
     y = y.astype(dt_) + xc * params["d_skip"].astype(dt_)[None, None]
 
     out = y * jax.nn.silu(z)

@@ -40,9 +40,11 @@ standing ``infer`` queries that run within one StreamRuntime tick join a
 single wave — N standing queries cost one wave per tick, sharing the
 params/jit caches — with ``ml/wave`` / ``ml/score`` (arch, rows) spans
 and ``repro_ml_*`` metrics (``repro_ml_tokens_scored_total`` by arch,
-``repro_ml_param_bytes`` per loaded model).  ``StreamRuntime.tick``
-mirrors ``stats()`` into ``Monitor.observe_ml`` so
-``admin.status()["ml"]`` tracks it live.
+``repro_ml_param_bytes`` per loaded model, and per scored window its
+Mamba layers under ``repro_ml_scan_kernel_total`` or
+``repro_ml_scan_reference_total``, by the path its forward's scan
+took).  ``StreamRuntime.tick`` mirrors ``stats()`` into
+``Monitor.observe_ml`` so ``admin.status()["ml"]`` tracks it live.
 
 Model handles are registered via ``BigDawg.register_model`` on an
 ``MLEngine`` (``bd.ensure_ml_engines``); the Planner pins ``infer``
@@ -68,7 +70,9 @@ from repro.stream.engine import (SEQ_FIELD, ShardedStream, Stream,
 try:  # pragma: no cover - exercised by monkeypatching JAX_AVAILABLE
     import jax
     import jax.numpy as jnp
-    from repro.models import registry
+    from repro.kernels import interpret_mode
+    from repro.kernels.mamba_scan import ops as scan_ops
+    from repro.models import mamba, registry
     from repro.serve.engine import TickWaveScheduler
     from repro.sharding import logical as _logical
     JAX_AVAILABLE = True
@@ -76,6 +80,7 @@ except ModuleNotFoundError as exc:  # any other import error raises
     if exc.name != "jax":
         raise
     jax = jnp = registry = _logical = None
+    interpret_mode = scan_ops = mamba = None
     TickWaveScheduler = None
     JAX_AVAILABLE = False
 
@@ -269,6 +274,30 @@ def score_tokens(loaded: _Loaded, tokens: np.ndarray):
     return nll.mean()
 
 
+def _count_scans(loaded: _Loaded, windows: List[np.ndarray]) -> None:
+    """Add each window's Mamba layers to the counter of the path its
+    forward's scan took, decided by ``scan_ops.uses_kernel``: the rule
+    ``apply_mamba`` dispatches by, on the platform the weights live on
+    and the window's length."""
+    cfg = loaded.cfg
+    layers = (sum(m == "mamba" for m, _ in cfg.layer_plan())
+              * cfg.num_scanned())
+    if not layers:
+        return
+    leaf = jax.tree.leaves(loaded.params)[0]
+    platform = "cpu" if interpret_mode(leaf) else "tpu"
+    for w in windows:
+        if scan_ops.uses_kernel(platform, int(w.shape[0]),
+                                mamba.d_inner(cfg)):
+            metrics.counter("repro_ml_scan_kernel_total",
+                            "Mamba layers of scored windows scanned by "
+                            "the Pallas kernel").inc(layers)
+        else:
+            metrics.counter("repro_ml_scan_reference_total",
+                            "Mamba layers of scored windows scanned by "
+                            "the reference lax.scan").inc(layers)
+
+
 # ---------------------------------------------------------------------------
 # the shim: infer(<window expr | name>, <model>[, field=...])
 # ---------------------------------------------------------------------------
@@ -386,6 +415,7 @@ def execute_ml(engine: Engine, query: str) -> dm.Table:
                         "tokens of every scored window",
                         arch=handle.arch).inc(
             sum(int(w.shape[0]) for w in windows))
+        _count_scans(loaded, windows)
         return dm.Table({
             "window": jnp.arange(n, dtype=jnp.int32),
             "rows": jnp.asarray([w.shape[0] for w in windows], jnp.int32),

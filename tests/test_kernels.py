@@ -107,6 +107,76 @@ def test_mamba_scan_sweep(b, s, di, n, bd, chunk):
                                atol=5e-5, rtol=5e-5)
 
 
+def _scan_inputs(b, s, di, n, dtype, h0_scale):
+    u = jnp.asarray(RNG.standard_normal((b, s, di)), dtype)
+    dt = jnp.asarray(RNG.uniform(0.001, 0.1, (b, s, di)), dtype)
+    a = jnp.asarray(-RNG.uniform(0.5, 2.0, (di, n)), jnp.float32)
+    bb = jnp.asarray(RNG.standard_normal((b, s, n)), dtype)
+    c = jnp.asarray(RNG.standard_normal((b, s, n)), dtype)
+    h0 = jnp.asarray(RNG.standard_normal((b, di, n)), jnp.float32) * h0_scale
+    return u, dt, a, bb, c, h0
+
+
+@pytest.mark.parametrize("b,s,di,n,bd,chunk,dtype,h0_scale", [
+    (1, 64, 256, 16, 128, 32, jnp.float32, 0.0),      # (N, BD) lanes
+    (1, 64, 256, 16, 256, 32, jnp.float32, 0.5),      # h0 seeded in VMEM
+    (2, 100, 128, 16, 128, 32, jnp.float32, 0.2),     # ragged S: dt = 0 pad
+    (1, 64, 256, 16, 128, 32, jnp.bfloat16, 0.2),     # bf16 u/dt/B/C
+    (1, 70, 384, 16, 128, 32, jnp.bfloat16, 0.2),     # ragged, 3 blocks
+], ids=["lanes", "h0", "ragged", "bf16", "bf16-ragged"])
+def test_mamba_scan_lane_dense(b, s, di, n, bd, chunk, dtype, h0_scale):
+    from repro.kernels.mamba_scan import ops, ref
+    u, dt, a, bb, c, h0 = _scan_inputs(b, s, di, n, dtype, h0_scale)
+    y, h = ops.selective_scan(u, dt, a, bb, c, h0=h0, bd=bd, chunk=chunk)
+    yr, hr = ref.selective_scan(u, dt, a, bb, c, h0)
+    assert y.shape == yr.shape and y.dtype == jnp.float32
+    assert h.shape == hr.shape and h.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(y), np.asarray(yr),
+                               atol=5e-5, rtol=5e-5)
+    np.testing.assert_allclose(np.asarray(h), np.asarray(hr),
+                               atol=5e-5, rtol=5e-5)
+
+
+def test_mamba_scan_grad_is_the_reference_grad():
+    from repro.kernels.mamba_scan import ops, ref
+    u, dt, a, bb, c, h0 = _scan_inputs(1, 40, 128, 16, jnp.float32, 0.3)
+    wy = jnp.asarray(RNG.standard_normal((1, 40, 128)), jnp.float32)
+    wh = jnp.asarray(RNG.standard_normal((1, 128, 16)), jnp.float32)
+
+    def loss(scan):
+        def f(*args):
+            y, h = scan(*args)
+            return jnp.sum(y * wy) + jnp.sum(h * wh)
+        return f
+
+    kernel = loss(lambda *x: ops.selective_scan(*x, bd=128, chunk=32))
+    got = jax.grad(kernel, argnums=range(6))(u, dt, a, bb, c, h0)
+    want = jax.grad(loss(ref.selective_scan), argnums=range(6))(
+        u, dt, a, bb, c, h0)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("platform,s,di,kernel", [
+    ("tpu", 2048, 5120, True),       # the icu-jamba2 cell's scan
+    ("cpu", 2048, 5120, False),
+    ("tpu", 1, 5120, False),         # decode
+    ("tpu", 2048, 5000, False),      # no whole 128-lane tiles
+])
+def test_mamba_scan_path(platform, s, di, kernel):
+    from repro.kernels.mamba_scan import ops
+    assert ops.uses_kernel(platform, s, di) is kernel
+
+
+def test_mamba_scan_on_the_cpu_is_the_reference_bitwise():
+    from repro.kernels.mamba_scan import ops, ref
+    u, dt, a, bb, c, h0 = _scan_inputs(1, 300, 128, 16, jnp.bfloat16, 0.2)
+    for got, want in zip(ops.scan(u, dt, a, bb, c, h0),
+                         ref.selective_scan(u, dt, a, bb, c, h0)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 # -- quant cast ----------------------------------------------------------------
 @pytest.mark.parametrize("shape", [(1000,), (64, 128), (3, 7, 33),
                                    (8, 128)])

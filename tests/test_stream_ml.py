@@ -279,6 +279,30 @@ def test_ml_counters_and_spans():
                          reduced=True).value == resident
 
 
+@pytest.mark.parametrize("platform", ["cpu", "tpu"])
+def test_scan_path_counters_follow_the_dispatch_rule(platform, monkeypatch):
+    """Each scored window adds its Mamba layers (6 in the reduced
+    jamba2-3b) to the counter of the path ``uses_kernel`` gives for the
+    weights' platform and the window's length; a model without Mamba
+    layers adds to neither."""
+    from repro.kernels.mamba_scan import mamba_scan as k
+    from repro.obs import metrics
+    rows = k.CHUNK
+    bd = default_deployment()
+    bd.register_model("mamba")
+    bd.register_model("lm")
+    bd.register_stream("streamstore0", StreamSpec("vitals.hr", ("ts", "hr"),
+                                                  capacity=2 * rows))
+    bd.engines["streamstore0"].get("vitals.hr").append(_rows(2 * rows))
+    monkeypatch.setattr(ml, "interpret_mode", lambda x: platform != "tpu")
+    names = ("repro_ml_scan_kernel_total", "repro_ml_scan_reference_total")
+    before = [metrics.counter(n).value for n in names]
+    bd.query(f"bdml(infer(window(vitals.hr, {rows}, {rows}), models.mamba))")
+    bd.query(f"bdml(infer(window(vitals.hr, {rows}, {rows}), models.lm))")
+    moved = [metrics.counter(n).value - b for n, b in zip(names, before)]
+    assert moved == ([2 * 6, 0] if platform == "tpu" else [0, 2 * 6])
+
+
 # -- failure modes ------------------------------------------------------------
 def test_incomplete_window_is_transient():
     from repro.core.executor import (DataUnavailableException,

@@ -131,11 +131,30 @@ def test_qwen2_forward_fits_one_chip(one_chip, sizes):
     assert total < HBM_BYTES, total
 
 
-def test_jamba2_forward_fits_one_chip(one_chip):
+def test_mamba_scan_kernel_compiles_at_the_cell_shape(one_chip):
+    """One Mamba layer of the icu-jamba2 scorer: S 2048, Di 5120, N 16,
+    bfloat16 u/dt/B/C, the kernel's own BD and chunk."""
+    from repro.kernels.mamba_scan import mamba_scan as k
+    s, di, n = 2048, 5120, 16
+    sd = lambda shape, dtype: jax.ShapeDtypeStruct(    # noqa: E731
+        shape, dtype, sharding=one_chip)
+    compiled = k.selective_scan_chunked.lower(
+        sd((1, s, di), jnp.bfloat16), sd((1, s, di), jnp.bfloat16),
+        sd((di, n), jnp.float32), sd((1, s, n), jnp.bfloat16),
+        sd((1, s, n), jnp.bfloat16), sd((1, di, n), jnp.float32),
+        interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_jamba2_forward_fits_one_chip(one_chip, monkeypatch):
     """The icu-jamba2 cell's scorer: AI21-Jamba2-3B at published widths,
-    bfloat16 weights as the ml island holds them, one (1, 2048) window."""
+    bfloat16 weights as the ml island holds them, one (1, 2048) window,
+    its 13 Mamba layers a scan period on the Pallas kernel, as on a TPU
+    (the trace here sees the CPU, so the test names the platform)."""
+    from repro.kernels.mamba_scan import ops
     from repro.models import registry
     from repro.stream import ml
+    monkeypatch.setattr(ops, "interpret_mode", lambda x: False)
     cfg = registry.get_config("jamba2-3b")
     params = jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
@@ -146,7 +165,9 @@ def test_jamba2_forward_fits_one_chip(one_chip):
     toks = jax.ShapeDtypeStruct((1, 2048), jnp.int32, sharding=one_chip)
     fwd = jax.jit(lambda p, t: registry.forward(p, {"tokens": t}, cfg,
                                                 None)[0])
-    mem = fwd.lower(params, toks).compile().memory_analysis()
+    compiled = fwd.lower(params, toks).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 13
+    mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes > 6e9          # the 6.06 GB weights
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
