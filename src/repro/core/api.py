@@ -173,56 +173,50 @@ class BigDawg:
 
     def _register_spec(self, engine_name: str, spec):
         """The one registration path (both API forms land here)."""
-        from repro.stream.engine import (SEQ_FIELD, ShardedStream, Stream,
-                                         StreamEngine)
+        from repro.stream.engine import Stream, StreamEngine
         assert isinstance(self.engines[engine_name], StreamEngine), \
             engine_name
-        name, fields = spec.name, spec.fields
-        et = spec.event_time
-        if spec.shards <= 1:
-            stream = Stream(name, fields, spec.capacity,
-                            rolling=spec.rolling, ts_field=spec.ts_field,
-                            max_delay=et.max_delay if et else 0.0,
-                            idle_timeout=et.idle_timeout if et else None)
-            stream.spec = spec
-            self.register_object(engine_name, name, stream,
-                                 fields=tuple(fields))
-            self._stream_extras(engine_name, stream, spec)
-            return stream
-        sh = spec.sharding
-        # ensure_stream_engines returns the whole (possibly larger)
-        # streaming island; spread the shards over only the first
-        # `num_engines` engines so the documented contract holds
-        engine_names = self.ensure_stream_engines(
-            sh.num_engines)[:sh.num_engines]
-        per_shard = max(1, -(-int(spec.capacity) // sh.shards))  # ceil
-        pairs = []
-        for i in range(sh.shards):
-            ename = engine_names[i % len(engine_names)]
-            shard = Stream(f"{name}@shard{i}",
-                           tuple(fields) + (SEQ_FIELD,),
-                           per_shard, rolling=spec.rolling)
-            self.register_object(ename, shard.name, shard,
-                                 fields=shard.fields)
-            pairs.append((ename, shard))
-        handle = ShardedStream(name, fields, pairs,
-                               shard_key=sh.shard_key,
-                               block_rows=sh.block_rows,
-                               ts_field=spec.ts_field,
-                               max_delay=et.max_delay if et else 0.0,
-                               idle_timeout=et.idle_timeout if et
-                               else None)
-        handle.spec = spec
-        # the handle lives on every participating engine AND the caller's
-        # anchor engine (shards always spread over streamstore0..spread-1,
-        # but engine_name must still resolve the logical stream)
-        participating = sorted(set(e for e, _ in pairs) | {engine_name})
-        self.register_object(participating[0], name, handle,
-                             fields=tuple(fields))
+        et, sh = spec.event_time, spec.sharding
+        placement, capacity = None, spec.capacity
+        if sh is not None:
+            # ensure_stream_engines returns the whole (possibly larger)
+            # streaming island; spread the rings over only the first
+            # `num_engines` engines so the documented contract holds
+            engines = self.ensure_stream_engines(
+                sh.num_engines)[:sh.num_engines]
+            placement = [engines[i % len(engines)]
+                         for i in range(sh.shards)]
+            capacity = max(1, -(-int(spec.capacity) // sh.shards))  # ceil
+        stream = Stream(spec.name, spec.fields, capacity,
+                        rolling=spec.rolling, ts_field=spec.ts_field,
+                        max_delay=et.max_delay if et else 0.0,
+                        idle_timeout=et.idle_timeout if et else None,
+                        shards=placement,
+                        shard_key=sh.shard_key if sh else None,
+                        block_rows=sh.block_rows if sh else 64)
+        stream.spec = spec
+        self._place_stream(engine_name, stream)
+        self._stream_extras(engine_name, stream, spec)
+        return stream
+
+    def _place_stream(self, engine_name: str, stream) -> None:
+        """Register a stream in the catalog: a multi-ring stream's rings
+        as ``{name}@shard{i}`` on their engines and its handle on every
+        participating engine AND ``engine_name`` (rings always spread
+        over streamstore0..spread-1, but the caller's engine must still
+        resolve the logical stream); a one-ring stream on
+        ``engine_name`` only."""
+        participating = {engine_name}
+        if stream.num_shards > 1:
+            for ename, ring in zip(stream.shard_engines(), stream.rings):
+                self.register_object(ename, ring.name, ring,
+                                     fields=ring.fields)
+                participating.add(ename)
+        participating = sorted(participating)
+        self.register_object(participating[0], stream.name, stream,
+                             fields=tuple(stream.fields))
         for ename in participating[1:]:
-            self.engines[ename].put(name, handle)
-        self._stream_extras(engine_name, handle, spec)
-        return handle
+            self.engines[ename].put(stream.name, stream)
 
     def _stream_extras(self, engine_name: str, stream, spec) -> None:
         """Shared tail of register_stream/recover_stream: dead-letter
@@ -262,24 +256,13 @@ class BigDawg:
         result = recover(directory)
         stream = result.stream
         meta = result  # RecoveryResult
-        if hasattr(stream, "shard_engines"):      # ShardedStream
-            engines = stream.shard_engines()
-            pool = [int(e[len("streamstore"):]) + 1 for e in engines
-                    if e.startswith("streamstore")
-                    and e[len("streamstore"):].isdigit()]
-            if pool:
-                self.ensure_stream_engines(max(pool))
-            for ename, shard in zip(engines, stream._shards):
-                self.register_object(ename, shard.name, shard,
-                                     fields=shard.fields)
-            participating = sorted(set(engines) | {engine_name})
-            self.register_object(participating[0], stream.name, stream,
-                                 fields=tuple(stream.fields))
-            for ename in participating[1:]:
-                self.engines[ename].put(stream.name, stream)
-        else:
-            self.register_object(engine_name, stream.name, stream,
-                                 fields=tuple(stream.fields))
+        pool = [int(e[len("streamstore"):]) + 1
+                for e in stream.shard_engines()
+                if e is not None and e.startswith("streamstore")
+                and e[len("streamstore"):].isdigit()]
+        if pool:
+            self.ensure_stream_engines(max(pool))
+        self._place_stream(engine_name, stream)
         if result.late_sink is not None:
             self.register_object(engine_name, result.late_sink.name,
                                  result.late_sink,

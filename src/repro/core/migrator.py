@@ -164,25 +164,25 @@ class Migrator:
         docstring: this route moves by default; ``copy=True`` builds a
         detached read replica instead and leaves the source live).
 
-        Shard moves are safe under concurrent producers:
-        ``ShardedStream.migrate_shard`` pauses the shard's ordered
-        committer, so every seq block reserved before the move drains
-        into the exported state and blocks reserved during it publish
-        to the new ring afterwards — in-flight reservations are carried,
-        never lost.  ``Stream.export_state`` likewise drains its own
-        committer first.  Only a *direct* move of an unsharded stream
+        The source is a ring of a multi-ring stream (``{name}@shardN``,
+        moved by ``Stream.migrate_shard``), a one-ring stream (moved
+        whole), or — copy mode only — any stream.  Ring moves are safe
+        under concurrent producers: ``migrate_shard`` pauses the ring's
+        ordered committer, so every seq block reserved before the move
+        drains into the exported state and blocks reserved during it
+        publish to the new ring afterwards — in-flight reservations are
+        carried, never lost.  ``Stream.export_state`` likewise drains
+        its lanes first.  Only a *direct* move of a one-ring stream
         still needs external serialization: a block reserved after the
         export but before the delete below lands in the doomed source
         object (pause the feed, or move between ticks)."""
-        from repro.stream.engine import (ShardedStream, Stream,
-                                         StreamEngine)
+        from repro.stream.engine import StreamEngine, movable
         obj = engine_from.get(object_from)
-        allowed = (Stream, ShardedStream) if copy else (Stream,)
-        if not isinstance(obj, allowed):
+        if not movable(obj, copy):
             raise MigrationException(
-                f"stream cast needs a "
-                f"{' or '.join(c.__name__ for c in allowed)} source, "
-                f"got {type(obj).__name__} for {object_from!r}")
+                f"stream cast needs a stream ring, a one-ring Stream or "
+                f"(copying) any Stream source, got {type(obj).__name__} "
+                f"for {object_from!r}")
         if not isinstance(engine_to, StreamEngine):
             raise MigrationException(
                 f"stream cast needs a StreamEngine destination, "
@@ -207,8 +207,7 @@ class Migrator:
                 replica = obj.clone(object_to)
             engine_to.put(object_to, replica)
             return
-        state = obj.export_state()
-        engine_to.put(object_to, Stream.from_state(state))
+        engine_to.put(object_to, type(obj).from_state(obj.export_state()))
         engine_from.delete(object_from)
         # a move changes physical placement, so the catalog must follow
         # (copy routes leave the source object untouched and don't)

@@ -233,7 +233,7 @@ class Planner:
             if holding:
                 members = holding
         if node.island == "streaming" and len(members) > 1 and refs:
-            # a ShardedStream handle lives on every participating
+            # a multi-ring stream's handle lives on every participating
             # StreamEngine, so all placements of a gather read are
             # semantically identical — pin to the referenced handles'
             # home engines instead of enumerating one plan per engine.

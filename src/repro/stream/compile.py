@@ -74,8 +74,10 @@ import numpy as np
 
 from repro.core import datamodel as dm
 from repro.obs import metrics, trace
-from repro.stream.engine import (_COMBINABLE_AGGS, ShardedStream, Stream,
-                                 StreamException, _latest_closed_ewindow,
+from repro.stream.engine import (_COMBINABLE_AGGS, SEQ_FIELD, Stream,
+                                 StreamException, _ewindow_evicted,
+                                 _latest_closed_ewindow, _too_few_rows,
+                                 _tumbling_start, _window_evicted,
                                  to_device)
 
 try:                                         # gate: jax may be absent
@@ -301,7 +303,7 @@ class Uncompilable(Exception):
 class CompiledStreamQuery:
     """One lowered streaming sub-plan bound to its stream object.
 
-    ``execute()`` runs per tick: the host stage takes the stream lock
+    ``execute()`` runs per tick: the host stage takes the ring lock
     only to export the point-in-time ring arrays (and resolve window
     bounds with the interpreter's own arithmetic, so every data-
     dependent StreamException — window not complete, evicted, watermark
@@ -317,46 +319,37 @@ class CompiledStreamQuery:
 
 
 # -- window lowerings -------------------------------------------------------
-def _export_stacked(stream: Stream) -> Tuple[int, int, np.ndarray]:
-    """(total_appended, count, (F, capacity) zero-padded oldest-first
-    rows) — one point-in-time ring export; the lock is held only for
-    the gather copy, exactly like the interpreter's ``_ordered`` reads."""
+def _export_stacked(stream: Stream, field: str = SEQ_FIELD,
+                    lo: float = -np.inf, hi: float = np.inf):
+    """One point-in-time export of a one-ring stream's ring: every row
+    oldest first as an (F, capacity) zero-padded array, with the ordered
+    offsets [a, b) of the rows whose ``field`` lies in [lo, hi) (see
+    ``_Ring.export``) — the ring lock is held only for the copy, exactly
+    like the interpreter's reads."""
     with trace.span("stream/gather", stream=stream.name,
-                    path="compiled") as sp, stream._lock:
-        count = stream._count
-        total = stream.total_appended
-        out = np.zeros((len(stream.fields), stream.capacity), np.float64)
-        for j, f in enumerate(stream.fields):
-            out[j, :count] = stream._ordered(f)
-        sp.set(rows=count)
-    return total, count, out
+                    path="compiled") as sp:
+        ex = stream.rings[0].export(stream.fields, field, lo, hi)
+        sp.set(rows=ex.count)
+    return ex
 
 
 def _compile_window(stream, size: int,
                     slide: Optional[int]) -> CompiledStreamQuery:
-    if not isinstance(stream, Stream):
-        raise Uncompilable("sharded window gathers stay interpreted")
+    if stream.num_shards > 1:
+        raise Uncompilable("multi-ring window gathers stay interpreted")
     if size <= 0 or (slide is not None and slide <= 0):
         raise Uncompilable("non-positive window size/slide")
     fields = stream.fields
 
     if slide is None:
         def run() -> dm.ArrayObject:
-            total, count, stacked = _export_stacked(stream)
-            first_seq = total - count
-            k = total // size - 1
-            if k < 0:
-                raise StreamException(
-                    f"stream {stream.name!r}: no complete window of "
-                    f"size {size} yet ({total} rows)")
-            s = k * size
-            if s < first_seq:
-                raise StreamException(
-                    f"stream {stream.name!r}: window [{s},{s + size}) "
-                    f"already evicted (buffer starts at {first_seq})")
+            s = _tumbling_start(stream.name, size, stream.total_appended)
+            ex = _export_stacked(stream, SEQ_FIELD, s, s + size)
+            if ex.b - ex.a != size:
+                raise _window_evicted(stream.name, s, s + size,
+                                      ex.b - ex.a)
             with jax.enable_x64(True):
-                out = _jit_tumbling(to_device(stacked), s - first_seq,
-                                    size=size)
+                out = _jit_tumbling(to_device(ex.rows), ex.a, size=size)
             # zero-copy np view, numpy slicing, one device_put per
             # field: eager jax slicing on the host path costs ~0.5ms
             # *per op* in dispatch, which would swamp the jitted gather
@@ -367,19 +360,23 @@ def _compile_window(stream, size: int,
 
         return CompiledStreamQuery("window", run)
 
-    max_windows = (stream.capacity - size) // slide + 1
+    max_windows = (stream.rings[0].capacity - size) // slide + 1
     if max_windows < 1:
         raise Uncompilable("window larger than ring capacity")
 
     def run_sliding() -> dm.ArrayObject:
-        _, count, stacked = _export_stacked(stream)
+        total = stream.total_appended
+        ex = _export_stacked(stream, SEQ_FIELD, -np.inf, total)
+        count = ex.b                       # rows below the frontier
+        if count and ex.first != total - count:
+            # a seq hole (a dead producer's abandoned block): the
+            # interpreter serves the contiguous suffix past it
+            raise Uncompilable("seq hole in the ring")
         if count < size:
-            raise StreamException(
-                f"stream {stream.name!r}: {count} rows < window "
-                f"size {size}")
+            raise _too_few_rows(stream.name, count, size)
         num = (count - size) // slide + 1
         with jax.enable_x64(True):
-            out = _jit_sliding(to_device(stacked), size=size, slide=slide,
+            out = _jit_sliding(to_device(ex.rows), size=size, slide=slide,
                                max_windows=max_windows)
         arr = np.asarray(trace.device_wait(out))  # zero-copy; slice in numpy
         return dm.ArrayObject(
@@ -391,31 +388,23 @@ def _compile_window(stream, size: int,
 
 def _compile_ewindow(stream, span: float,
                      slide: Optional[float]) -> CompiledStreamQuery:
-    if not isinstance(stream, Stream):
-        raise Uncompilable("sharded ewindow gathers stay interpreted")
+    if stream.num_shards > 1:
+        raise Uncompilable("multi-ring ewindow gathers stay interpreted")
     if stream.ts_field is None:
         raise Uncompilable("ewindow over a stream with no ts_field")
     fields = stream.fields
 
     def run() -> dm.ArrayObject:
         start, end = _latest_closed_ewindow(stream, span, slide)
-        with trace.span("stream/gather", stream=stream.name,
-                        path="compiled") as sp, stream._lock:
-            if start <= stream._evicted_ts:
-                raise StreamException(
-                    f"stream {stream.name!r}: ewindow [{start},{end}) "
-                    f"already evicted (rows up to ts "
-                    f"{stream._evicted_ts} overwritten)")
-            a, b = stream._seq_bounds_locked(stream.ts_field, start, end)
-            count = stream._count
-            stacked = np.zeros((len(fields), stream.capacity),
-                               np.float64)
-            for j, f in enumerate(fields):
-                stacked[j, :count] = stream._ordered(f)
-            sp.set(rows=count)
-        m = b - a
+        ex = _export_stacked(stream, stream.ts_field, start, end)
+        # checked after the export: the boundary only advances, so one
+        # that reaches the window now covered it during the export too
+        evicted = stream.evicted_ts
+        if start <= evicted:
+            raise _ewindow_evicted(stream.name, start, end, evicted)
+        m = ex.b - ex.a
         with jax.enable_x64(True):
-            out = _jit_rows(to_device(stacked), a,
+            out = _jit_rows(to_device(ex.rows), ex.a,
                             length=_pow2(max(m, 1)))
         arr = np.asarray(trace.device_wait(out))  # zero-copy; slice in numpy
         return dm.ArrayObject(
@@ -467,7 +456,7 @@ def _compile_aggregate(engine, expr: str, fn: str,
 # -- join lowering ----------------------------------------------------------
 def _operand(engine, expr: str) -> Callable[[], dm.ArrayObject]:
     """A join operand evaluator: the compiled gather when the operand
-    is in the family, else the interpreter's (sharded ewindows, bare
+    is in the family, else the interpreter's (multi-ring ewindows, bare
     snapshots — their host gathers are the lowering either way; the
     jitted matcher still runs on the result)."""
     try:
@@ -646,7 +635,7 @@ def _plan_anchor(engine, query: str):
             obj = engine.get(tok)
         except Exception:                    # noqa: BLE001 — not a name
             continue
-        if isinstance(obj, (Stream, ShardedStream)):
+        if isinstance(obj, Stream):
             return obj
     return None
 

@@ -184,8 +184,8 @@ class StreamRuntime:
 
     # -- the tick loop --------------------------------------------------------
     def _streams_map(self) -> Dict[str, Any]:
-        """Every stream any StreamEngine serves (plain rings, shard
-        rings, and sharded handles — handles deduped by name)."""
+        """Every stream any StreamEngine serves (a multi-ring stream's
+        handle, registered on several engines, deduped by name)."""
         from repro.stream.engine import StreamEngine
         streams: Dict[str, Any] = {}
         for engine in self.engines.values():
@@ -230,7 +230,7 @@ class StreamRuntime:
         query can never see."""
         if streams is None:
             streams = self._streams_map()
-        return sum(getattr(streams[r], "total_late", 0)
+        return sum(streams[r].total_late
                    for r in self._refs_for(cq, streams))
 
     def _watermarks_for(self, cq: ContinuousQuery,
@@ -243,7 +243,7 @@ class StreamRuntime:
             streams = self._streams_map()
         marks = {r: streams[r].watermark
                  for r in self._refs_for(cq, streams)
-                 if getattr(streams[r], "ts_field", None) is not None}
+                 if streams[r].ts_field is not None}
         return marks or None
 
     def tick(self) -> List[Tuple[str, Any]]:
@@ -298,11 +298,9 @@ class StreamRuntime:
         # idle-timeout punctuation runs BEFORE the standing queries, so
         # a watermark advance it produces unsticks watermark-gated
         # queries on this very tick (not the next one)
-        for name, stream in streams_map.items():
-            if "@shard" in name:
-                continue
-            if (getattr(stream, "idle_timeout", None) is not None
-                    and getattr(stream, "ts_field", None) is not None):
+        for stream in streams_map.values():
+            if (stream.idle_timeout is not None
+                    and stream.ts_field is not None):
                 stream.advance_idle_watermark()
         for cq in due:
             if cq.event_time:
@@ -378,13 +376,9 @@ class StreamRuntime:
         # per-stream low watermarks land there too (event-time health:
         # admin.status()["streams"] and the Monitor agree by construction)
         for name, stream in streams_map.items():
-            if "@shard" in name:
-                continue
             # multi-producer ingest counters for every logical stream
-            ic = getattr(stream, "ingest_concurrency", None)
-            if ic is not None:
-                self.monitor.observe_ingest(name, ic())
-            if getattr(stream, "ts_field", None) is None:
+            self.monitor.observe_ingest(name, stream.ingest_concurrency())
+            if stream.ts_field is None:
                 continue
             self.monitor.observe_watermark(
                 name, stream.watermark, late=stream.total_late,
@@ -392,8 +386,7 @@ class StreamRuntime:
             # event-time eviction horizon: rows at or below this ts have
             # been overwritten — windows over them raise (a gauge, so
             # alerting can catch consumers falling behind the ring)
-            ev = (stream._evicted_ts if hasattr(stream, "_evicted_ts")
-                  else max(s._evicted_ts for s in stream._shards))
+            ev = stream.evicted_ts
             if ev != float("-inf"):
                 metrics.gauge(
                     "repro_stream_eviction_ts",
@@ -429,16 +422,9 @@ class StreamRuntime:
         return [self.tick() for _ in range(n)]
 
     def _sharded_streams(self) -> Dict[str, Any]:
-        """Logical name -> ShardedStream handle (deduped: the handle is
-        registered on every participating StreamEngine)."""
-        from repro.stream.engine import ShardedStream, StreamEngine
-        out: Dict[str, Any] = {}
-        for engine in self.engines.values():
-            if isinstance(engine, StreamEngine):
-                for sname, obj in engine.streams().items():
-                    if isinstance(obj, ShardedStream):
-                        out[sname] = obj
-        return out
+        """Logical name -> stream, for the streams with several rings."""
+        return {name: stream for name, stream in self._streams_map().items()
+                if stream.num_shards > 1}
 
     # -- background tick driver (opt-in) --------------------------------------
     def start(self, interval_seconds: float = 0.05) -> None:
@@ -582,7 +568,7 @@ class StreamRuntime:
 
     # -- introspection --------------------------------------------------------
     def status(self) -> Dict[str, Any]:
-        from repro.stream.engine import ShardedStream, StreamEngine
+        from repro.stream.engine import StreamEngine
         with self._lock:
             out: Dict[str, Any] = {
                 "ticks": self.ticks,
@@ -601,19 +587,12 @@ class StreamRuntime:
             if not isinstance(engine, StreamEngine):
                 continue
             for sname, stream in engine.streams().items():
-                if "@shard" in sname:
-                    continue          # shard rings report under the handle
                 if sname in out["streams"]:
                     continue          # a handle lives on several engines;
                     #                   gather its stats only once
                 info = stream.stats()
-                if isinstance(stream, ShardedStream):
-                    info["engine"] = stream.shard_engines()
-                    info["shard_key"] = stream.shard_key
-                    info["agg_cache_hits"] = stream.agg_cache_hits
-                    info["agg_computes"] = stream.agg_computes
-                else:
-                    info["engine"] = ename
+                info["engine"] = (stream.shard_engines()
+                                  if stream.num_shards > 1 else ename)
                 info["rows_per_second"] = round(stream.rate(), 1)
                 out["streams"][sname] = info
         return out

@@ -1,4 +1,4 @@
-"""Crash-safe streams: per-shard append-only segment log + checkpoint /
+"""Crash-safe streams: per-ring append-only segment log + checkpoint /
 recover / replay (ROADMAP direction 5).
 
 Durability is **opt-in per stream** (``register_stream(...,
@@ -10,12 +10,16 @@ durability=dir)`` or :func:`attach`).  Three pieces:
   section, after the ring write published, so the ingest hot path
   gains no locks and readers never wait on log I/O.  Lanes:
 
-  * seq-ordered ``Stream``: one lane of ``APPEND`` records;
-  * seq-ordered ``ShardedStream``: one lane **per shard** of ``SHARD``
-    records, each carrying its block's bounds so recovery can cut a
-    block whose shards were not all logged before a crash;
-  * event-time streams (both kinds): ingest is lock-serialized, so one
-    lane of ``ARRIVE`` records (the raw arrival batches, logged before
+  * seq-ordered streams: one lane **per ring** of ``SHARD`` records
+    (the ring's slice of a block, seq column included), each carrying
+    its block's bounds so recovery can cut a block whose rings were not
+    all logged before a crash — a one-ring stream logs one lane — plus
+    a ``holes`` lane of ``HOLE`` records: the bounds of every block that
+    will never fully publish (a failed stage or ring commit, or a block
+    the frontier reaped after its producer stalled), so recovery and
+    replica catch-up step over it instead of stopping there;
+  * event-time streams: ingest is lock-serialized, so one lane of
+    ``ARRIVE`` records (the raw arrival batches, logged before
     late classification so replay reproduces ``total_late`` and the
     dead-letter sink) plus ``FLUSH`` records for explicit/idle
     punctuation (external input a replay cannot re-derive).
@@ -56,25 +60,26 @@ import struct
 import threading
 import time
 import zlib
-from typing import Any, Dict, Iterator, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
 from repro.checkpoint.manager import CheckpointManager
 from repro.obs import metrics, trace
 from repro.runtime.fault import crash_point
-from repro.stream.engine import (SEQ_FIELD, ShardedStream, Stream,
-                                 StreamException)
+from repro.stream.engine import SEQ_FIELD, Stream, StreamException
 
 # -- record framing ----------------------------------------------------------
 # little-endian: lsn u64 | kind u8 | block i64 | block_total i64 |
 #                nrows u32 | payload_len u32 | crc32(payload) u32
 _HDR = struct.Struct("<QBqqIII")
 
-KIND_APPEND = 1      # plain seq-ordered batch      payload: fields
-KIND_SHARD = 2       # one shard's slice of a block payload: fields+__seq
+KIND_SHARD = 2       # one ring's slice of a block  payload: fields+__seq
 KIND_ARRIVE = 3      # raw event-time arrival batch payload: fields
 KIND_FLUSH = 4       # punctuation                  payload: target ts
+KIND_HOLE = 5        # a block that never published payload: 1.0 if reaped
+# records whose payload is one float64 (``Record.target``), not columns
+_SCALAR_KINDS = (KIND_FLUSH, KIND_HOLE)
 
 _META_KEY = "meta"   # checkpoint leaf holding the JSON-encoded structure
 
@@ -86,8 +91,8 @@ class Record:
     block: int
     total: int
     nrows: int
-    cols: Optional[Dict[str, np.ndarray]]   # None for FLUSH
-    target: float                           # FLUSH only
+    cols: Optional[Dict[str, np.ndarray]]   # None for FLUSH and HOLE
+    target: float                           # FLUSH and HOLE only
     size: int                               # bytes on disk
 
 
@@ -169,7 +174,7 @@ class SegmentLog:
         an armed kill produces exactly the on-disk states a real kill
         could: nothing, a torn (header-only) record, or a whole record
         with the in-memory successor state lost."""
-        if kind == KIND_FLUSH:
+        if kind in _SCALAR_KINDS:
             payload = np.float64(target).tobytes()
         else:
             payload = b"".join(
@@ -265,7 +270,7 @@ def _scan_segment(path: str, first_lsn: int, fields: Tuple[str, ...]
         payload = data[off + _HDR.size:end]
         if zlib.crc32(payload) != crc:
             return out, off, True
-        if kind == KIND_FLUSH:
+        if kind in _SCALAR_KINDS:
             cols, target = None, float(np.frombuffer(payload,
                                                      np.float64)[0])
         else:
@@ -322,9 +327,10 @@ def _decode(spec, arrays: Dict[str, np.ndarray]):
 class StreamDurability:
     """Owns one durable stream's lanes, checkpoint manager, and cadence
     bookkeeping.  Installed as ``stream._durable`` by :func:`attach`;
-    the engine hot paths call ``log_append``/``log_shard``/
-    ``log_arrive``/``log_flush`` (each from within the serialization
-    domain that makes its lane single-writer)."""
+    the engine hot paths call ``log_shard``/``log_arrive``/
+    ``log_flush``/``log_hole`` (each from within the serialization
+    domain that makes its lane single-writer: a ring's ordered commit,
+    the stream lock, the committed frontier's lock)."""
 
     def __init__(self, stream, directory: str, *,
                  checkpoint_every_rows: Optional[int] = None,
@@ -337,19 +343,9 @@ class StreamDurability:
         if fsync is None:
             fsync = os.environ.get("REPRO_LOG_FSYNC", "0") == "1"
         os.makedirs(directory, exist_ok=True)
-        self.sharded = isinstance(stream, ShardedStream)
-        wal = os.path.join(directory, "wal")
-        if self.sharded and stream.ts_field is None:
-            self.lanes = {
-                f"shard{i}": SegmentLog(
-                    os.path.join(wal, f"shard{i}"),
-                    tuple(stream.fields) + (SEQ_FIELD,),
-                    segment_bytes=segment_bytes, fsync=fsync)
-                for i in range(stream.num_shards)}
-        else:
-            self.lanes = {"lane0": SegmentLog(
-                os.path.join(wal, "lane0"), tuple(stream.fields),
-                segment_bytes=segment_bytes, fsync=fsync)}
+        self.lanes = _open_lanes(directory, stream.fields,
+                                 stream.ts_field, stream.num_shards,
+                                 segment_bytes=segment_bytes, fsync=fsync)
         self.manager = CheckpointManager(
             os.path.join(directory, "ckpt"), keep=self.keep)
         latest = self.manager.latest_step()
@@ -367,45 +363,29 @@ class StreamDurability:
         if os.path.exists(path):
             return
         s = self.stream
-        meta = {"name": s.name, "fields": list(s.fields),
+        meta = {"kind": "stream", "name": s.name,
+                "fields": list(s.fields),
                 "ts_field": s.ts_field, "max_delay": s.max_delay,
                 "idle_timeout": s.idle_timeout,
                 "keep": self.keep,
                 "checkpoint_every_rows": self.checkpoint_every_rows,
-                "dead_letter": s._late_sink is not None}
-        if self.sharded:
-            # record the *logical* registration capacity too: per-shard
-            # capacities are a ceil-division of it, so summing them back
-            # would inflate the figure and break the StreamSpec
-            # manifest round-trip (spec ≡ from_manifest(meta))
-            spec = getattr(s, "spec", None)
-            meta.update(kind="sharded",
-                        shard_key=s.shard_key,
-                        block_rows=s.block_rows,
-                        engines=s.shard_engines(),
-                        shard_capacities=[sh.capacity
-                                          for sh in s._shards],
-                        rolling=s._shards[0].rolling,
-                        capacity=(spec.capacity if spec is not None
-                                  else sum(sh.capacity
-                                           for sh in s._shards)))
-        else:
-            meta.update(kind="stream", capacity=s.capacity,
-                        rolling=s.rolling)
+                "dead_letter": s._late_sink is not None,
+                "shard_key": s.shard_key, "block_rows": s.block_rows,
+                "engines": s.shard_engines(),
+                "ring_capacity": s.rings[0].capacity,
+                "rolling": s.rolling,
+                # the *logical* registration capacity: ring capacities
+                # are a ceil-division of it, so multiplying back would
+                # inflate the figure and break the StreamSpec manifest
+                # round-trip (spec ≡ from_manifest(meta))
+                "capacity": (s.spec.capacity if s.spec is not None
+                             else s.capacity)}
         tmp = path + ".tmp"
         with open(tmp, "w") as f:
             json.dump(meta, f, indent=1)
         os.replace(tmp, path)
 
     # -- write-behind log hooks (called from engine.py) ------------------------
-    def log_append(self, seq_start: int,
-                   cols: Dict[str, np.ndarray], n: int) -> None:
-        with trace.span("stream/log_append", stream=self.stream.name,
-                        rows=n):
-            self.lanes["lane0"].append(KIND_APPEND, seq_start, n,
-                                       cols, n)
-        self._count_rows(n)
-
     def log_shard(self, shard: int, block: int, total: int,
                   payload: Dict[str, np.ndarray]) -> None:
         n = payload[SEQ_FIELD].shape[0]
@@ -424,6 +404,13 @@ class StreamDurability:
     def log_flush(self, target: float) -> None:
         self.lanes["lane0"].append(KIND_FLUSH, -1, 0, None, 0,
                                    target=target)
+
+    def log_hole(self, block: int, total: int, abandoned: bool) -> None:
+        """Seq block [block, block+total) will never fully publish; its
+        ring slices that did publish are already logged on their lanes.
+        ``abandoned``: the frontier reaped it (``blocks_abandoned``)."""
+        self.lanes["holes"].append(KIND_HOLE, block, total, None, 0,
+                                   target=float(abandoned))
 
     def _count_rows(self, n: int) -> None:
         metrics.counter("repro_stream_log_records_total",
@@ -468,8 +455,7 @@ class StreamDurability:
                         "late_sink": None}
                 sink = self.stream._late_sink
                 if sink is not None:
-                    with sink._lock:
-                        caps["late_sink"] = sink._export_locked()
+                    caps["late_sink"] = sink.export_state()
                 return caps
 
             state, caps = self.stream._checkpoint_snapshot(capture)
@@ -567,7 +553,7 @@ def attach(stream, directory: str, *,
 
 @dataclasses.dataclass
 class RecoveryResult:
-    stream: Any                       # Stream | ShardedStream (detached)
+    stream: Stream                    # detached: unregistered, no log hook
     late_sink: Optional[Stream]
     checkpoint_step: Optional[int]
     records_replayed: int
@@ -595,36 +581,48 @@ def recover(directory: str, *, repair: bool = True) -> RecoveryResult:
     step = manager.latest_step()
     with trace.span("stream/replay", stream=meta["name"],
                     checkpoint=step if step is not None else -1):
+        sink = None
+        lsns: Dict[str, int] = {}
         if step is not None:
             flat = manager.restore_flat(step)
             spec = json.loads(str(flat.pop(_META_KEY)))
             payload = _decode(spec, flat)
-            state = payload["state"]
-            if meta["kind"] == "sharded":
-                stream = ShardedStream.from_state(state)
-            else:
-                stream = Stream.from_state(state)
-            sink = (Stream.from_state(payload["late_sink"])
-                    if payload.get("late_sink") is not None else None)
+            stream = Stream.from_state(payload["state"])
+            if payload.get("late_sink") is not None:
+                sink = Stream.from_state(payload["late_sink"])
             lsns = {k: int(v) for k, v in payload["lsns"].items()}
         else:
-            stream = _fresh_stream(meta)
-            sink = (_fresh_sink(meta) if meta.get("dead_letter")
-                    else None)
-            lsns = {}
+            stream = Stream(meta["name"], meta["fields"],
+                            meta["ring_capacity"], rolling=meta["rolling"],
+                            ts_field=meta["ts_field"],
+                            max_delay=meta["max_delay"],
+                            idle_timeout=meta["idle_timeout"],
+                            shards=meta["engines"],
+                            shard_key=meta["shard_key"],
+                            block_rows=meta["block_rows"])
         if sink is None and meta.get("dead_letter"):
-            sink = _fresh_sink(meta)
+            sink = Stream(f"{meta['name']}.__late", meta["fields"],
+                          meta["capacity"])
         stream._late_sink = sink
 
-        lanes = _open_lanes(meta, directory)
+        lanes = _open_lanes(directory, meta["fields"], meta["ts_field"],
+                            len(meta["engines"]))
         records = {lane: log.scan(lsns.get(lane, 0), repair=repair)
                    for lane, log in lanes.items()}
-        if meta["kind"] == "sharded" and meta["ts_field"] is None:
-            replayed, rows, cut = _replay_sharded(stream, lanes,
-                                                  records, repair)
+        if meta["ts_field"] is not None:
+            replayed, rows = _replay_arrivals(stream, records["lane0"])
+            cut = 0
         else:
-            replayed, rows, cut = _replay_single(stream,
-                                                 records["lane0"])
+            replayed, rows = _apply_blocks(stream, records)
+            # cut: every lane record of a block at/after the frontier
+            # (a crash landed between its ring commits, or between a
+            # ring publish and its log append) — per lane a suffix
+            cut = 0
+            for lane, recs in records.items():
+                bad = [r for r in recs if r.block >= stream.total_appended]
+                cut += len(bad)
+                if bad and repair:
+                    lanes[lane].truncate_from(bad[0].lsn)
         for log in lanes.values():
             log.close()
     seconds = time.perf_counter() - t0
@@ -640,192 +638,112 @@ def recover(directory: str, *, repair: bool = True) -> RecoveryResult:
                           seconds=seconds, truncated_records=cut)
 
 
-def _fresh_stream(meta: Dict[str, Any]):
-    if meta["kind"] == "sharded":
-        pairs = []
-        for i, (ename, cap) in enumerate(zip(meta["engines"],
-                                             meta["shard_capacities"])):
-            shard = Stream(f"{meta['name']}@shard{i}",
-                           tuple(meta["fields"]) + (SEQ_FIELD,),
-                           cap, rolling=meta.get("rolling", True))
-            pairs.append((ename, shard))
-        return ShardedStream(meta["name"], meta["fields"], pairs,
-                             shard_key=meta.get("shard_key"),
-                             block_rows=meta.get("block_rows", 64),
-                             ts_field=meta.get("ts_field"),
-                             max_delay=meta.get("max_delay", 0.0),
-                             idle_timeout=meta.get("idle_timeout"))
-    return Stream(meta["name"], meta["fields"], meta["capacity"],
-                  rolling=meta.get("rolling", True),
-                  ts_field=meta.get("ts_field"),
-                  max_delay=meta.get("max_delay", 0.0),
-                  idle_timeout=meta.get("idle_timeout"))
-
-
-def _fresh_sink(meta: Dict[str, Any]) -> Stream:
-    capacity = (meta["capacity"] if meta["kind"] == "stream"
-                else sum(meta["shard_capacities"]))
-    return Stream(f"{meta['name']}.__late", meta["fields"], capacity)
-
-
-def _open_lanes(meta: Dict[str, Any],
-                directory: str) -> Dict[str, SegmentLog]:
+def _open_lanes(directory: str, fields, ts_field: Optional[str],
+                rings: int, **log_kwargs) -> Dict[str, SegmentLog]:
+    """A stream's log lanes: one ``shard{i}`` lane of ``SHARD`` records
+    per ring plus the ``holes`` lane for a seq-ordered stream, one
+    ``lane0`` of ``ARRIVE`` and ``FLUSH`` records for an event-time
+    stream."""
     wal = os.path.join(directory, "wal")
-    if meta["kind"] == "sharded" and meta["ts_field"] is None:
-        return {f"shard{i}": SegmentLog(
-            os.path.join(wal, f"shard{i}"),
-            tuple(meta["fields"]) + (SEQ_FIELD,))
-            for i in range(len(meta["engines"]))}
-    return {"lane0": SegmentLog(os.path.join(wal, "lane0"),
-                                tuple(meta["fields"]))}
+    if ts_field is not None:
+        return {"lane0": SegmentLog(os.path.join(wal, "lane0"),
+                                    tuple(fields), **log_kwargs)}
+    lanes = {f"shard{i}": SegmentLog(os.path.join(wal, f"shard{i}"),
+                                     tuple(fields) + (SEQ_FIELD,),
+                                     **log_kwargs)
+             for i in range(rings)}
+    lanes["holes"] = SegmentLog(os.path.join(wal, "holes"), (),
+                                **log_kwargs)
+    return lanes
 
 
-def _apply_plain(stream: Stream, cols: Dict[str, np.ndarray],
-                 n: int) -> None:
-    """Re-apply one committed batch to a (shard) ring exactly as
-    ``_append_prepared``'s publish would have — same counters, same
-    single write path."""
-    with stream._lock:
-        stream.blocks_reserved += 1
-        stream.rows_reserved += n
-        stream._ingest_locked(cols, n)
-        stream._append_times.append((time.monotonic(), n))
+def _replay_arrivals(stream: Stream, records: List[Record]
+                     ) -> Tuple[int, int]:
+    """Replay an event-time stream's lane in lsn order through its live
+    ingest (arrival batches re-run late classification; punctuation
+    re-applies the logged watermark).  Returns (records, rows)."""
+    for rec in records:
+        if rec.kind == KIND_ARRIVE:
+            stream.append(rec.cols)
+        else:
+            stream.flush(rec.target)
+    return len(records), sum(rec.nrows for rec in records)
 
 
-def _replay_single(stream, records: List[Record]
-                   ) -> Tuple[int, int, int]:
-    """Replay a single-lane log (plain stream, or any event-time
-    stream) in lsn order.  Returns (records, rows, records cut)."""
-    replayed = rows = 0
-    for i, rec in enumerate(records):
-        if rec.kind == KIND_APPEND:
-            if rec.block != stream.total_appended:
-                # seq discontinuity: the record belongs to a different
-                # history than the restored state — unrecoverable tail
-                return replayed, rows, len(records) - i
-            _apply_plain(stream, rec.cols, rec.nrows)
-        elif rec.kind == KIND_ARRIVE:
-            stream._append_event_time(rec.cols, rec.nrows)
-        elif rec.kind == KIND_FLUSH:
-            with stream._lock:
-                stream._flush_locked(rec.target)
-        replayed += 1
-        rows += rec.nrows
-    return replayed, rows, 0
-
-
-def _replay_sharded(stream: ShardedStream,
-                    lanes: Dict[str, SegmentLog],
-                    records: Dict[str, List[Record]],
-                    repair: bool) -> Tuple[int, int, int]:
-    """Replay per-shard lanes by reassembling blocks: a block is
-    applied only when the records across lanes account for every one
-    of its rows, and only in contiguous seq order from the restored
-    frontier.  Everything after the first incomplete block (a crash
-    landed between its shard commits, or between ring publish and log
-    append) is cut — per lane those records are a suffix, truncated
-    physically with ``repair=True`` so the next recovery agrees."""
-    blocks: Dict[int, Dict[str, Any]] = {}
+def _apply_blocks(stream: Stream, records: Dict[str, List[Record]]
+                  ) -> Tuple[int, int]:
+    """Replay seq lanes (one per ring) by reassembling blocks: a block
+    is applied only when the records across lanes account for every one
+    of its rows — or a ``HOLE`` record says it never will, when the
+    slices that did publish are applied — and only in contiguous seq
+    order from the stream's frontier: the first incomplete block stops
+    it.  Returns (records, rows) applied."""
+    blocks: Dict[int, Dict[int, Record]] = {}
+    holes: Dict[int, Record] = {}
     for lane, recs in records.items():
-        shard = int(lane[len("shard"):])
+        if lane == "holes":
+            holes.update((rec.block, rec) for rec in recs)
+            continue
+        ring = int(lane[len("shard"):])
         for rec in recs:
-            entry = blocks.setdefault(rec.block,
-                                      {"total": rec.total, "parts": []})
-            entry["parts"].append((shard, rec))
+            blocks.setdefault(rec.block, {})[ring] = rec
     replayed = rows = 0
-    frontier = stream.total_appended
-    while frontier in blocks:
-        entry = blocks[frontier]
-        total = entry["total"]
-        if sum(r.nrows for _, r in entry["parts"]) != total:
+    while True:
+        t = stream.total_appended
+        parts, hole = blocks.get(t, {}), holes.get(t)
+        logged = sum(r.nrows for r in parts.values())
+        if hole is not None:
+            total = hole.total
+        elif parts and logged == next(iter(parts.values())).total:
+            total = logged
+        else:
             break
-        for shard, rec in sorted(entry["parts"]):
-            _apply_plain(stream._shards[shard], rec.cols, rec.nrows)
-            replayed += 1
-            rows += rec.nrows
-        with stream._frontier:
-            stream.total_appended += total
-        stream.reserved = stream.total_appended
-        stream.blocks_reserved += 1
-        stream.rows_reserved += total
-        frontier = stream.total_appended
-    # cut: every lane record belonging to a block at/after the frontier
-    cut = 0
-    for lane, recs in records.items():
-        bad = [r for r in recs if r.block >= frontier]
-        if bad:
-            cut += len(bad)
-            if repair:
-                lanes[lane].truncate_from(bad[0].lsn)
-    return replayed, rows, cut
+        stream._apply_block(t, total, {i: r.cols for i, r in parts.items()},
+                            abandoned=hole is not None and hole.target > 0)
+        replayed += len(parts) + (hole is not None)
+        rows += logged
+    return replayed, rows
 
 
 # -- fingerprint: the recovered ≡ original equality ---------------------------
 
-def fingerprint(stream) -> Dict[str, Any]:
+def fingerprint(stream: Stream) -> Dict[str, Any]:
     """A comparable digest of everything ``recovered ≡ original``
     promises: counters, watermarks, ring contents (exact bytes, in seq
     order), pending buffers, and the dead-letter sink.  Wall-clock-only
     state (append-time history, idle arrival stamps) is excluded."""
     import hashlib
 
-    def ring_digest(s: Stream) -> Dict[str, Any]:
+    def sha(arrays) -> str:
         h = hashlib.sha256()
-        with s._lock:
-            for f in s.fields:
-                h.update(s._ordered(f).tobytes())
-            pend = hashlib.sha256()
-            for b in s._pending:
-                for f in s.fields:
-                    pend.update(np.ascontiguousarray(
-                        b[f], np.float64).tobytes())
-            return {"name": s.name, "rows": s._count, "next": s._next,
-                    "total_appended": s.total_appended,
-                    "total_dropped": s.total_dropped,
-                    "blocks_reserved": s.blocks_reserved,
-                    "rows_reserved": s.rows_reserved,
-                    "watermark": s.watermark,
-                    "max_ts_seen": s.max_ts_seen,
-                    "min_ts_seen": s.min_ts_seen,
-                    "total_late": s.total_late,
-                    "pending_rows": s._pending_rows,
-                    "evicted_ts": s._evicted_ts,
-                    "ring": h.hexdigest(), "pending": pend.hexdigest()}
+        for a in arrays:
+            h.update(np.ascontiguousarray(a, np.float64).tobytes())
+        return h.hexdigest()
 
-    if isinstance(stream, ShardedStream):
-        with stream._lock:
-            pend = hashlib.sha256()
-            for b in stream._pending:
-                for f in stream.fields:
-                    pend.update(np.ascontiguousarray(
-                        b[f], np.float64).tobytes())
-            for a in stream._pending_arrivals:
-                pend.update(np.ascontiguousarray(a, np.int64).tobytes())
-            out = {"name": stream.name,
-                   "total_appended": stream.total_appended,
-                   "total_dropped": stream.total_dropped,
-                   "blocks_reserved": stream.blocks_reserved,
-                   "rows_reserved": stream.rows_reserved,
-                   "blocks_abandoned": stream.blocks_abandoned,
-                   "watermark": stream.watermark,
-                   "max_ts_seen": stream.max_ts_seen,
-                   "min_ts_seen": stream.min_ts_seen,
-                   "total_late": stream.total_late,
-                   "pending_rows": stream._pending_rows,
-                   "arrivals": stream._arrivals,
-                   "shard_max_ts": list(stream._shard_max_ts),
-                   "pending": pend.hexdigest(),
-                   "shards": [ring_digest(s) for s in stream._shards]}
-    else:
-        out = ring_digest(stream)
+    state = stream.export_state()
+    out = {k: state[k] for k in (
+        "name", "total_appended", "blocks_reserved", "rows_reserved",
+        "blocks_abandoned", "watermark", "max_ts_seen", "min_ts_seen",
+        "total_late", "shard_max_ts")}
+    out["pending_rows"] = sum(b[stream.fields[0]].shape[0]
+                              for b in state["pending"])
+    out["pending"] = sha(b[f] for b in state["pending"]
+                         for f in stream.fields)
+    out["shards"] = [{"name": r["name"],
+                      "rows": r["cols"][SEQ_FIELD].shape[0],
+                      "total_appended": r["total_appended"],
+                      "total_dropped": r["total_dropped"],
+                      "evicted_ts": r["evicted_ts"],
+                      "ring": sha(r["cols"][f] for f in r["fields"])}
+                     for r in state["rings"]]
     if stream._late_sink is not None:
-        out["late_sink"] = ring_digest(stream._late_sink)
+        out["late_sink"] = fingerprint(stream._late_sink)
     return out
 
 
 # -- replica catch-up ---------------------------------------------------------
 
-def catch_up(replica, durable: StreamDurability) -> Dict[str, Any]:
+def catch_up(replica: Stream, durable: StreamDurability) -> Dict[str, Any]:
     """Bring a read replica (Migrator stream-route *copy* mode) up to
     date with its primary by replaying the primary's live segment log
     from the per-lane positions stored on the replica at copy time
@@ -835,30 +753,26 @@ def catch_up(replica, durable: StreamDurability) -> Dict[str, Any]:
     Read-only against the log (``repair=False`` — a concurrent
     writer's half-flushed tail is skipped, never cut) and incremental:
     the replica's lane floors advance past every applied record, so
-    repeated calls replay only the delta.  For seq-sharded primaries a
-    block is applied only once every shard slice of it has been
-    logged; an incomplete tail block stays pending until the next
-    call."""
+    repeated calls replay only the delta.  A seq block is applied only
+    once every ring slice of it has been logged; an incomplete tail
+    block stays pending until the next call."""
     t0 = time.perf_counter()
     floors: Dict[str, int] = dict(
         getattr(replica, "_replica_lsns", None) or {})
     with trace.span("stream/catch_up", stream=replica.name):
         records = {lane: log.scan(floors.get(lane, 0), repair=False)
                    for lane, log in durable.lanes.items()}
-        if (isinstance(replica, ShardedStream)
-                and replica.ts_field is None):
-            replayed, rows, applied = _catch_up_sharded(replica,
-                                                        records)
+        if replica.ts_field is not None:
+            replayed, rows = _replay_arrivals(replica, records["lane0"])
         else:
-            recs = records["lane0"]
-            replayed, rows, _ = _replay_single(replica, recs)
-            applied = {"lane0": (recs[replayed - 1].lsn + 1
-                                 if replayed else None)}
-    for lane in durable.lanes:
-        if applied.get(lane) is not None:
-            floors[lane] = max(floors.get(lane, 0), applied[lane])
-        else:
-            floors.setdefault(lane, 0)
+            replayed, rows = _apply_blocks(replica, records)
+    for lane, recs in records.items():
+        # the next floor: the lane's first unapplied record
+        pending = [r.lsn for r in recs
+                   if replica.ts_field is None
+                   and r.block >= replica.total_appended]
+        nxt = pending[0] if pending else (recs[-1].lsn + 1 if recs else 0)
+        floors[lane] = max(floors.get(lane, 0), nxt)
     replica._replica_lsns = floors
     metrics.counter("repro_stream_replica_catchup_rows_total",
                     "rows applied to read replicas from the primary's "
@@ -866,45 +780,6 @@ def catch_up(replica, durable: StreamDurability) -> Dict[str, Any]:
                     stream=replica.name).inc(rows)
     return {"records": replayed, "rows": rows,
             "seconds": time.perf_counter() - t0, "lsns": dict(floors)}
-
-
-def _catch_up_sharded(stream: ShardedStream,
-                      records: Dict[str, List[Record]]
-                      ) -> Tuple[int, int, Dict[str, Optional[int]]]:
-    """The incremental (non-repairing) sibling of ``_replay_sharded``:
-    apply complete blocks in contiguous seq order from the replica's
-    frontier, and report per-lane the first *unapplied* lsn (the next
-    catch-up floor) — ``None`` when the lane had no records to scan."""
-    blocks: Dict[int, Dict[str, Any]] = {}
-    for lane, recs in records.items():
-        shard = int(lane[len("shard"):])
-        for rec in recs:
-            entry = blocks.setdefault(rec.block,
-                                      {"total": rec.total, "parts": []})
-            entry["parts"].append((shard, rec))
-    replayed = rows = 0
-    frontier = stream.total_appended
-    while frontier in blocks:
-        entry = blocks[frontier]
-        total = entry["total"]
-        if sum(r.nrows for _, r in entry["parts"]) != total:
-            break                      # incomplete tail block: wait
-        for shard, rec in sorted(entry["parts"]):
-            _apply_plain(stream._shards[shard], rec.cols, rec.nrows)
-            replayed += 1
-            rows += rec.nrows
-        with stream._frontier:
-            stream.total_appended += total
-        stream.reserved = stream.total_appended
-        stream.blocks_reserved += 1
-        stream.rows_reserved += total
-        frontier = stream.total_appended
-    applied: Dict[str, Optional[int]] = {}
-    for lane, recs in records.items():
-        pending = [r.lsn for r in recs if r.block >= frontier]
-        applied[lane] = (pending[0] if pending
-                         else (recs[-1].lsn + 1 if recs else None))
-    return replayed, rows, applied
 
 
 # -- replay-as-loadgen --------------------------------------------------------

@@ -64,8 +64,8 @@ import numpy as np
 from repro.core import datamodel as dm
 from repro.core.engines import Engine
 from repro.obs import metrics, trace
-from repro.stream.engine import (SEQ_FIELD, ShardedStream, Stream,
-                                 StreamEngine, StreamException)
+from repro.stream.engine import (SEQ_FIELD, Stream, StreamEngine,
+                                 StreamException)
 
 try:  # pragma: no cover - exercised by monkeypatching JAX_AVAILABLE
     import jax
@@ -311,7 +311,7 @@ def _find_stream_engine(engines: Dict[str, Engine],
     for ename in sorted(engines):
         e = engines[ename]
         if (isinstance(e, StreamEngine) and e.has(name)
-                and isinstance(e.get(name), (Stream, ShardedStream))):
+                and isinstance(e.get(name), Stream)):
             return e
     return None
 

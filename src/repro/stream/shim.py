@@ -36,19 +36,19 @@ island data-model objects, so ``bdcast`` moves them into the array island
 ``join`` emits a plain Table, so joined results migrate to any island
 over the existing staged casts.
 
-``join`` of two ewindows over ShardedStreams with *co-located* shards
-(identical engine placements) takes a partial-join fast path: the left
-window is split into per-shard bands and each band joins against only
+``join`` of two ewindows over multi-ring streams with *co-located*
+rings (identical engine placements) takes a partial-join fast path: the
+left window is split into per-ring bands and each band joins against only
 the right rows within ``tol`` of it, so the work decomposes the way the
 data is placed.  The banded result is bit-identical to the full join
 (each left row lives in exactly one band and keeps all its matches).
 
-All ops are shard-transparent: a ``ShardedStream`` handle (one logical
-stream hash-partitioned across several StreamEngines) answers the same
-snapshot/window/aggregate/rate calls with seq-ordered gathers, and
-``aggregate(window(S, n), fn(attr))`` over a tumbling window takes the
-rolling fast path — per-shard partial aggregates combined, memoized per
-window index — instead of materializing the window each tick.
+All ops are ring-transparent: a ``Stream`` partitioned across rings on
+several StreamEngines answers the same snapshot/window/aggregate/rate
+calls with seq-ordered merges, and ``aggregate(window(S, n),
+fn(attr))`` over a tumbling window takes the rolling fast path —
+per-ring partial aggregates combined, memoized per window index —
+instead of materializing the window each tick.
 """
 from __future__ import annotations
 
@@ -62,7 +62,7 @@ import numpy as np
 from repro.core import datamodel as dm
 from repro.core.engines import Engine
 from repro.obs import metrics
-from repro.stream.engine import (_COMBINABLE_AGGS, ShardedStream, Stream,
+from repro.stream.engine import (_COMBINABLE_AGGS, Stream,
                                  StreamException, to_device)
 
 _AGG_RE = re.compile(r"^(count|sum|avg|min|max)\(\s*(\*|[\w\.]+)\s*\)$",
@@ -71,8 +71,8 @@ _AGG_RE = re.compile(r"^(count|sum|avg|min|max)\(\s*(\*|[\w\.]+)\s*\)$",
 # a tumbling (no slide) window, directly aggregated
 _WINDOW_AGG_RE = re.compile(
     r"^window\(\s*([\w\.]+)\s*,\s*(\d+)\s*\)$", re.IGNORECASE)
-# join(ewindow(S, ...), ewindow(T, ...)): when both streams are sharded
-# with co-located shards, the join takes the banded partial path
+# join(ewindow(S, ...), ewindow(T, ...)): when both streams' rings are
+# co-located, the join takes the banded partial path
 _EWINDOW_RE = re.compile(r"^ewindow\(\s*([\w\.]+)\s*,", re.IGNORECASE)
 _KWARG_RE = re.compile(r"^(\w+)\s*=\s*(.+)$")
 
@@ -217,7 +217,7 @@ def _split_args(s: str) -> List[str]:
 
 def _get_stream(engine: Engine, name: str):
     obj = engine.get(name.strip())
-    if not isinstance(obj, (Stream, ShardedStream)):
+    if not isinstance(obj, Stream):
         raise StreamException(f"{name!r} is not a stream on {engine.name}")
     return obj
 
@@ -225,9 +225,10 @@ def _get_stream(engine: Engine, name: str):
 def _colocated_bands(engine: Engine, left_expr: str,
                      right_expr: str) -> int:
     """Partial-join band count: when both join operands are ewindows
-    over ShardedStreams whose shards are co-located (identical engine
-    placement, shard for shard), decompose the join into one band per
-    shard pair; otherwise 1 (the plain full join)."""
+    over streams whose rings are co-located (identical engine
+    placement, ring for ring), decompose the join into one band per
+    ring pair — one band for one-ring streams, the plain full join;
+    otherwise 1."""
     lm = _EWINDOW_RE.match(left_expr.strip())
     rm = _EWINDOW_RE.match(right_expr.strip())
     if not (lm and rm):
@@ -237,8 +238,7 @@ def _colocated_bands(engine: Engine, left_expr: str,
         rs = _get_stream(engine, rm.group(1))
     except Exception:                                     # noqa: BLE001
         return 1
-    if (isinstance(ls, ShardedStream) and isinstance(rs, ShardedStream)
-            and ls.shard_engines() == rs.shard_engines()):
+    if ls.shard_engines() == rs.shard_engines():
         return ls.num_shards
     return 1
 
@@ -345,8 +345,8 @@ def execute_stream(engine: Engine, query: str):
         if not agg:
             raise ValueError(f"bad streaming aggregate: {args[1]!r}")
         # rolling fast path: a tumbling window aggregated on a real field
-        # never materializes the window — O(1) cumulative-ring partials
-        # (per shard for sharded streams), memoized per window index
+        # never materializes the window — O(1) cumulative-ring partials,
+        # one per ring, memoized per window index
         win = _WINDOW_AGG_RE.match(args[0].strip())
         agg_fn, target = agg.group(1).lower(), agg.group(2)
         if win and agg_fn in _COMBINABLE_AGGS:

@@ -215,15 +215,6 @@ class StreamSpec:
         return out
 
     # -- durability manifest (meta.json) round-trip ---------------------------
-    def manifest_extras(self) -> Dict[str, Any]:
-        """Spec-derived keys the durability layer folds into its
-        ``meta.json`` (on top of the runtime facts — engines, shard
-        capacities — only the live stream knows)."""
-        return {"capacity": self.capacity, "keep": self.keep_or_default()}
-
-    def keep_or_default(self) -> int:
-        return self.durability.keep if self.durability else 3
-
     @classmethod
     def from_manifest(cls, meta: Dict[str, Any],
                       directory: Optional[str] = None) -> "StreamSpec":
@@ -235,18 +226,12 @@ class StreamSpec:
         (the manifest never records it: the directory is where the
         manifest *lives*, and the tree may have been copied)."""
         sharding = None
-        if meta["kind"] == "sharded":
-            engines = meta["engines"]
+        engines = meta["engines"]
+        if len(engines) > 1:
             sharding = Sharding(shards=len(engines),
-                                shard_key=meta.get("shard_key"),
+                                shard_key=meta["shard_key"],
                                 num_engines=len(set(engines)),
-                                block_rows=meta.get("block_rows", 64))
-            capacity = meta.get("capacity",
-                                sum(meta["shard_capacities"]))
-            rolling = meta.get("rolling", True)
-        else:
-            capacity = meta["capacity"]
-            rolling = meta.get("rolling", True)
+                                block_rows=meta["block_rows"])
         event_time = None
         if meta.get("ts_field") is not None:
             event_time = EventTime(meta["ts_field"],
@@ -261,6 +246,6 @@ class StreamSpec:
                 checkpoint_every_rows=meta.get("checkpoint_every_rows"),
                 keep=meta.get("keep", 3))
         return cls(meta["name"], tuple(meta["fields"]),
-                   capacity=capacity, rolling=rolling,
+                   capacity=meta["capacity"], rolling=meta["rolling"],
                    sharding=sharding, event_time=event_time,
                    durability=durable)

@@ -492,7 +492,7 @@ def test_late_and_eviction_metrics_exported(registry):
     assert late["ev.stream"] == 1
     ev = {r["labels"]["stream"]: r["value"] for r in
           snap["repro_stream_eviction_ts"]["series"]}
-    assert ev["ev.stream"] == stream._evicted_ts > float("-inf")
+    assert ev["ev.stream"] == stream.evicted_ts > float("-inf")
     wm = {r["labels"]["stream"]: r["value"] for r in
           snap["repro_stream_watermark"]["series"]}
     assert wm["ev.stream"] == stream.watermark

@@ -41,8 +41,7 @@ from hypothesis import strategies as st  # noqa: E402
 
 from repro.runtime import fault  # noqa: E402
 from repro.stream import durability as dur  # noqa: E402
-from repro.stream.engine import (SEQ_FIELD, ShardedStream,  # noqa: E402
-                                 Stream)
+from repro.stream.engine import Stream  # noqa: E402
 
 EXAMPLES = int(os.environ.get("REPRO_CRASH_EXAMPLES", "40"))
 COMMON = dict(deadline=None, derandomize=bool(os.environ.get("CI")),
@@ -139,9 +138,9 @@ def sharded_experiment(draw):
 
 
 def _mk_sharded(nshards, block_rows):
-    shards = [(f"e{i}", Stream(f"w@shard{i}", ("a", SEQ_FIELD), 128))
-              for i in range(nshards)]
-    return ShardedStream("w", ("a",), shards, block_rows=block_rows)
+    return Stream("w", ("a",), 128,
+                  shards=[f"e{i}" for i in range(nshards)],
+                  block_rows=block_rows)
 
 
 @settings(max_examples=EXAMPLES, **COMMON)

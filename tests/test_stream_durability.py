@@ -21,7 +21,7 @@ from repro.checkpoint.manager import CheckpointManager
 from repro.core.api import default_deployment
 from repro.runtime import fault
 from repro.stream import durability as dur
-from repro.stream.engine import SEQ_FIELD, ShardedStream, Stream
+from repro.stream.engine import Stream
 
 
 @pytest.fixture(autouse=True)
@@ -48,7 +48,7 @@ def test_segment_log_roundtrip_and_roll(tmp_path):
     batches = [{f: rng.normal(size=5) for f in ("a", "b")}
                for _ in range(7)]
     for i, cols in enumerate(batches):
-        assert log.append(dur.KIND_APPEND, i * 5, 5, cols, 5) == i
+        assert log.append(dur.KIND_SHARD, i * 5, 5, cols, 5) == i
     assert len(log._segments()) > 1          # tiny cap forced rolls
     recs = dur.SegmentLog(str(tmp_path), ("a", "b")).scan()
     assert [r.lsn for r in recs] == list(range(7))
@@ -61,8 +61,8 @@ def test_segment_log_roundtrip_and_roll(tmp_path):
 
 def test_segment_log_torn_tail_detected_and_repaired(tmp_path):
     log = dur.SegmentLog(str(tmp_path), ("a",))
-    log.append(dur.KIND_APPEND, 0, 3, {"a": np.ones(3)}, 3)
-    log.append(dur.KIND_APPEND, 3, 2, {"a": np.ones(2)}, 2)
+    log.append(dur.KIND_SHARD, 0, 3, {"a": np.ones(3)}, 3)
+    log.append(dur.KIND_SHARD, 3, 2, {"a": np.ones(2)}, 2)
     log.close()
     # tear the last record's payload
     _, path = log._segments()[-1]
@@ -72,7 +72,7 @@ def test_segment_log_torn_tail_detected_and_repaired(tmp_path):
     # a reopened log repairs the tear and reuses the lsn
     log2 = dur.SegmentLog(str(tmp_path), ("a",))
     assert log2.next_lsn == 1
-    log2.append(dur.KIND_APPEND, 3, 4, {"a": np.zeros(4)}, 4)
+    log2.append(dur.KIND_SHARD, 3, 4, {"a": np.zeros(4)}, 4)
     recs = log2.scan()
     assert [r.lsn for r in recs] == [0, 1]
     assert recs[1].nrows == 4
@@ -81,7 +81,7 @@ def test_segment_log_torn_tail_detected_and_repaired(tmp_path):
 def test_segment_log_crc_corruption_stops_scan(tmp_path):
     log = dur.SegmentLog(str(tmp_path), ("a",))
     for i in range(3):
-        log.append(dur.KIND_APPEND, i, 1, {"a": np.full(1, i)}, 1)
+        log.append(dur.KIND_SHARD, i, 1, {"a": np.full(1, i)}, 1)
     log.close()
     _, path = log._segments()[0]
     with open(path, "r+b") as f:           # flip one payload byte of rec 1
@@ -96,11 +96,11 @@ def test_segment_log_crc_corruption_stops_scan(tmp_path):
 def test_truncate_from_and_prune(tmp_path):
     log = dur.SegmentLog(str(tmp_path), ("a",), segment_bytes=64)
     for i in range(10):
-        log.append(dur.KIND_APPEND, i, 1, {"a": np.full(1, i)}, 1)
+        log.append(dur.KIND_SHARD, i, 1, {"a": np.full(1, i)}, 1)
     log.truncate_from(6)
     assert [r.lsn for r in log.scan()] == list(range(6))
     assert log.next_lsn == 6
-    log.append(dur.KIND_APPEND, 6, 1, {"a": np.zeros(1)}, 1)
+    log.append(dur.KIND_SHARD, 6, 1, {"a": np.zeros(1)}, 1)
     assert [r.lsn for r in log.scan()] == list(range(7))
     nseg = len(log._segments())
     log.prune_below(5)
@@ -138,9 +138,8 @@ def test_plain_recover_without_checkpoint(tmp_path):
 
 def test_sharded_recover_bit_identical(tmp_path):
     rng = np.random.default_rng(3)
-    shards = [(f"e{i}", Stream(f"w@shard{i}", ("a", "b", SEQ_FIELD), 64))
-              for i in range(3)]
-    ss = ShardedStream("w", ("a", "b"), shards, block_rows=8)
+    ss = Stream("w", ("a", "b"), 64, shards=["e0", "e1", "e2"],
+                block_rows=8)
     h = dur.attach(ss, str(tmp_path))
     for i in range(9):
         n = int(rng.integers(1, 30))
@@ -175,11 +174,8 @@ def test_event_time_recover_with_late_and_flush(tmp_path):
 
 def test_sharded_event_time_recover(tmp_path):
     rng = np.random.default_rng(5)
-    shards = [(f"e{i}", Stream(f"x@shard{i}", ("ts", "v", SEQ_FIELD), 64,
-                               ts_field="ts"))
-              for i in range(2)]
-    ss = ShardedStream("x", ("ts", "v"), shards, block_rows=8,
-                       ts_field="ts", max_delay=4.0)
+    ss = Stream("x", ("ts", "v"), 64, shards=["e0", "e1"], block_rows=8,
+                ts_field="ts", max_delay=4.0)
     h = dur.attach(ss, str(tmp_path))
     ts = np.arange(48, dtype=float)
     for k in range(0, 48, 6):
@@ -196,7 +192,8 @@ def test_recover_after_wal_prune(tmp_path):
     without its log tail."""
     rng = np.random.default_rng(6)
     s = Stream("p", ("a",), 16)
-    h = dur.attach(s, str(tmp_path), keep=2, segment_bytes=256)
+    # two 8-row records (field + seq column, 165 bytes each) per segment
+    h = dur.attach(s, str(tmp_path), keep=2, segment_bytes=384)
     for i in range(30):
         s.append({"a": rng.normal(size=8)})
         if i % 10 == 9:
@@ -292,19 +289,14 @@ def test_sharded_crash_cuts_incomplete_block(tmp_path):
     rng = np.random.default_rng(9)
 
     def build(d):
-        shards = [(f"e{i}",
-                   Stream(f"w@shard{i}", ("a", SEQ_FIELD), 64))
-                  for i in range(2)]
-        ss = ShardedStream("w", ("a",), shards, block_rows=4)
+        ss = Stream("w", ("a",), 64, shards=["e0", "e1"], block_rows=4)
         dur.attach(ss, str(d))
         return ss
 
     batches = [rng.normal(size=10) for _ in range(3)]  # span both shards
 
     # uncrashed reference: fingerprint after every append
-    ref_shards = [(f"e{i}", Stream(f"w@shard{i}", ("a", SEQ_FIELD), 64))
-                  for i in range(2)]
-    ref = ShardedStream("w", ("a",), ref_shards, block_rows=4)
+    ref = Stream("w", ("a",), 64, shards=["e0", "e1"], block_rows=4)
     snaps = [dur.fingerprint(ref)]
     for v in batches:
         ref.append({"a": v})
@@ -337,6 +329,79 @@ def test_sharded_crash_cuts_incomplete_block(tmp_path):
         # and the repaired log re-recovers to the same state
         assert (dur.fingerprint(dur.recover(str(d)).stream)
                 == dur.fingerprint(rs))
+
+
+def _denamed(fp):
+    fp = dict(fp, name=None)
+    fp["shards"] = [dict(d, name=None) for d in fp["shards"]]
+    return fp
+
+
+@pytest.mark.parametrize("cause,rings", [("stall", 1), ("stage", 1),
+                                         ("commit", 2)])
+def test_seq_hole_is_durable(tmp_path, cause, rings):
+    """A block that never fully publishes — its producer died holding a
+    commit ticket (stolen after a stall, the frontier reaps the block),
+    its staging raised, or one ring's write raised — is a permanent seq
+    hole.  Its bounds are logged, so recovery and replica catch-up step
+    over it as the live frontier did: every acknowledged row after it
+    comes back, bit-identically, and nothing is cut."""
+    s = Stream("h", ("a",), 256, block_rows=4,
+               shards=["e0", "e1"] if rings == 2 else None)
+    durable = dur.attach(s, str(tmp_path))
+    s.append({"a": np.arange(16.0)})
+    # a read replica copied before the hole (the Migrator's copy mode)
+    state, lsns = s._checkpoint_snapshot(durable.lane_lsns)
+    replica = s.clone("h.replica", state=state)
+    replica._replica_lsns = lsns
+    if cause == "stall":
+        # the hard kill: reserve a seq block and its ticket, never stage
+        with s._reserve_lock:
+            t, n = s.reserved, 8
+            s.reserved += n
+            tickets = {0: s._committers[0].issue()}
+            s.blocks_reserved += 1
+            s.rows_reserved += n
+        with s._frontier:
+            s._pending_blocks[t] = (n, tickets)
+        s._committers[0].stall_timeout = 0.2      # keep the test fast
+    else:
+        target, name = ((s, "_partition") if cause == "stage"
+                        else (s.rings[1], "write"))
+        real = getattr(target, name)
+
+        def fail_once(*args):
+            setattr(target, name, real)
+            raise RuntimeError("injected")
+
+        setattr(target, name, fail_once)
+        with pytest.raises(RuntimeError, match="injected"):
+            s.append({"a": np.arange(100.0, 108.0)})
+    acked = [np.arange(16.0)]
+    for k in range(3):
+        batch = np.arange(10.0) + 200.0 + 10 * k
+        s.append({"a": batch})
+        acked.append(batch)
+    assert s.total_appended == 16 + 8 + 30            # the hole counted
+    assert s.ingest_concurrency()["blocks_abandoned"] == (cause == "stall")
+    live = np.asarray(s.snapshot().columns["a"])
+    assert np.isin(np.concatenate(acked), live).all()
+
+    r = dur.recover(str(tmp_path))
+    assert r.truncated_records == 0
+    assert dur.fingerprint(r.stream) == dur.fingerprint(s)
+    np.testing.assert_array_equal(
+        np.asarray(r.stream.snapshot().columns["a"]), live)
+    # the repaired log re-recovers to the same state
+    assert (dur.fingerprint(dur.recover(str(tmp_path)).stream)
+            == dur.fingerprint(s))
+
+    caught = dur.catch_up(replica, durable)
+    assert caught["rows"] == len(live) - 16
+    assert _denamed(dur.fingerprint(replica)) == \
+        _denamed(dur.fingerprint(s))
+    assert dur.catch_up(replica, durable)["rows"] == 0   # incremental
+    s.close()
 
 
 # -- dead-letter side stream -------------------------------------------------

@@ -13,8 +13,7 @@ import pytest
 from repro.core import admin, bql
 from repro.core.api import default_deployment
 from repro.stream import shim
-from repro.stream.engine import (ShardedStream, Stream, StreamEngine,
-                                 StreamException)
+from repro.stream.engine import Stream, StreamException
 
 
 def _jittered(ts, rng, jitter):
@@ -149,14 +148,10 @@ def test_ewindow_evicted_window_raises():
 # -- sharded event time -------------------------------------------------------
 def _mk_sharded(name, fields, shards, capacity=256, shard_key=None,
                 block_rows=4, ts_field="ts", max_delay=3.0):
-    engines = [StreamEngine(f"streamstore{i}") for i in range(shards)]
-    parts = [(e.name, e.create_stream(f"{name}@shard{i}",
-                                      tuple(fields) + ("__seq",),
-                                      -(-capacity // shards)))
-             for i, e in enumerate(engines)]
-    return ShardedStream(name, fields, parts, shard_key=shard_key,
-                         block_rows=block_rows, ts_field=ts_field,
-                         max_delay=max_delay)
+    return Stream(name, fields, -(-capacity // shards),
+                  shards=[f"streamstore{i}" for i in range(shards)],
+                  shard_key=shard_key, block_rows=block_rows,
+                  ts_field=ts_field, max_delay=max_delay)
 
 
 def test_sharded_out_of_order_gather_bit_identical_to_unsharded():

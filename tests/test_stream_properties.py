@@ -89,7 +89,7 @@ def test_plain_stream_invariants_hold_under_any_sequence(ops, capacity):
 def test_sharded_gather_bit_identical_to_unsharded(
         ops, capacity, shards, block_rows, shard_key):
     """The same append sequence through a plain Stream and through a
-    ShardedStream gathers bit-identically while no shard ring has
+    stream over several rings gathers bit-identically while no ring has
     evicted (capacity is split per shard, so this run keeps totals
     under the smallest ring)."""
     total = sum(len(arg) for op, arg in ops if op == "append")

@@ -1,9 +1,10 @@
 """Sharded streaming scale-out tests (arXiv:1609.07548 §streams across
-engines): scatter/gather round-trips bit-identical to the single-shard
-stream, rolling window aggregates via per-shard partials, live shard
-migration (Migrator ``stream`` route) preserving seq/drop accounting
-mid-standing-query, the Monitor-driven rebalance hook, and the opt-in
-background tick driver."""
+engines): a stream over 1, 2 or 3 rings — round-robin or key-hashed —
+reads bit-identically to the one-ring stream (snapshots, windows,
+ewindows, aggregates from per-ring partials, state round trips), live
+ring migration (Migrator ``stream`` route) preserving seq/drop
+accounting mid-standing-query, the Monitor-driven rebalance hook, and
+the opt-in background tick driver."""
 import threading
 import time
 
@@ -14,57 +15,97 @@ from repro.core import admin
 from repro.core.api import default_deployment
 from repro.core.migrator import MigrationException, MigrationParams
 from repro.core.monitor import Monitor
-from repro.stream.engine import ShardedStream, Stream, StreamEngine
+from repro.stream.engine import Stream
+
+# (rings, shard_key): one ring is the unsharded stream itself
+RING_LAYOUTS = [(n, key) for n in (1, 2, 3) for key in (None, "x")]
 
 
-def _mk_pair(shards=3, capacity=96, fields=("x", "y"), shard_key=None,
-             block_rows=4):
-    """(unsharded reference Stream, equivalent ShardedStream)."""
-    ref = Stream("s", fields, capacity)
-    engines = [StreamEngine(f"streamstore{i}") for i in range(shards)]
-    parts = [(e.name, e.create_stream(f"s@shard{i}",
-                                      tuple(fields) + ("__seq",),
-                                      -(-capacity // shards)))
-             for i, e in enumerate(engines)]
-    return ref, ShardedStream("s", fields, parts, shard_key=shard_key,
-                              block_rows=block_rows)
+def _mk(rings, capacity=96, fields=("x", "y"), shard_key=None,
+        block_rows=4, **kwargs):
+    """A stream named ``s`` holding ``capacity`` rows over ``rings``
+    rings (placed on streamstore0..N-1 when there are several)."""
+    placement = ([f"streamstore{i}" for i in range(rings)]
+                 if rings > 1 else None)
+    return Stream("s", fields, -(-capacity // rings), shards=placement,
+                  shard_key=shard_key, block_rows=block_rows, **kwargs)
 
 
-# -- scatter/gather equals the single-shard result ----------------------------
-@pytest.mark.parametrize("shard_key", [None, "x"])
-def test_gather_bit_identical_to_unsharded(shard_key):
-    ref, sh = _mk_pair(shards=3, shard_key=shard_key)
+# -- every ring layout reads like the one-ring stream -------------------------
+@pytest.mark.parametrize("rings,shard_key", RING_LAYOUTS)
+def test_gather_bit_identical_to_unsharded(rings, shard_key):
+    # each ring holds every row: no layout evicts
+    ref = Stream("s", ("x", "y"), 96)
+    sh = _mk(rings, capacity=96 * rings, shard_key=shard_key)
     rng = np.random.default_rng(0)
     for _ in range(6):
         batch = {"x": rng.integers(0, 9, 13).astype(float),
                  "y": rng.standard_normal(13)}
         ref.append(batch)
         sh.append(batch)
-    for view in (lambda s: s.snapshot().columns["y"],
-                 lambda s: s.snapshot().columns["seq"],
-                 lambda s: s.window(32).attrs["y"],        # tumbling
-                 lambda s: s.window(16, 8).attrs["y"]):    # sliding
-        np.testing.assert_array_equal(np.asarray(view(ref)),
-                                      np.asarray(view(sh)))
-    assert ref.total_appended == sh.total_appended == 78
+    views = (lambda s: s.snapshot().columns["y"],
+             lambda s: s.snapshot().columns["seq"],
+             lambda s: s.window(32).attrs["y"],        # tumbling
+             lambda s: s.window(16, 8).attrs["y"])     # sliding
+    # the export_state/from_state/clone copies read the same too
+    copies = (sh, Stream.from_state(sh.export_state()), sh.clone("s2"))
+    for copy in copies:
+        assert copy.num_shards == rings
+        assert copy.total_appended == ref.total_appended == 78
+        for view in views:
+            np.testing.assert_array_equal(np.asarray(view(ref)),
+                                          np.asarray(view(copy)))
+    # event time: the same jittered feed (no row late in any layout)
+    # gives the same closed ewindows, tumbling and sliding
+    eref = Stream("e", ("ts", "x"), 256, ts_field="ts", max_delay=3.0)
+    esh = _mk(rings, capacity=256, fields=("ts", "x"),
+              shard_key=shard_key, ts_field="ts", max_delay=3.0)
+    ts = np.arange(96, dtype=float)
+    order = np.argsort(ts + rng.uniform(-1.4, 1.4, 96))
+    for a in range(0, 96, 16):
+        batch = {"ts": ts[order[a:a + 16]], "x": ts[order[a:a + 16]] % 7}
+        eref.append(dict(batch))
+        esh.append(dict(batch))
+    for s in (eref, esh):
+        s.flush()
+    assert eref.total_late == esh.total_late == 0
+    for copy in (esh, esh.clone("e2")):
+        for view in (lambda s: s.snapshot().columns["ts"],
+                     lambda s: s.ewindow(8.0).attrs["x"],
+                     lambda s: s.ewindow(8.0, 4.0).attrs["ts"]):
+            np.testing.assert_array_equal(np.asarray(view(eref)),
+                                          np.asarray(view(copy)))
 
 
+@pytest.mark.parametrize("data", ["normal", "integer"])
 @pytest.mark.parametrize("fn", ["count", "sum", "avg", "min", "max"])
-def test_window_aggregate_partials_match_unsharded(fn):
-    ref, sh = _mk_pair(shards=3)
+@pytest.mark.parametrize("rings,shard_key", RING_LAYOUTS)
+def test_window_aggregate_partials_match_unsharded(rings, shard_key, fn,
+                                                   data):
+    ref = Stream("s", ("x", "y"), 192)
+    sh = _mk(rings, capacity=192, shard_key=shard_key)
     rng = np.random.default_rng(1)
     raw = []
     for _ in range(5):
-        batch = {"x": rng.standard_normal(16),
-                 "y": rng.standard_normal(16)}
+        if data == "normal":
+            batch = {"x": rng.standard_normal(16),
+                     "y": rng.standard_normal(16)}
+        else:
+            batch = {"x": rng.integers(0, 9, 16).astype(float),
+                     "y": rng.integers(-50, 50, 16).astype(float)}
         raw.append(batch["y"])
         ref.append(batch)
         sh.append(batch)
     win = np.concatenate(raw)[32:64]           # latest complete 32-window
     direct = {"count": float(len(win)), "sum": win.sum(),
               "avg": win.mean(), "min": win.min(), "max": win.max()}[fn]
+    got = sh.window_aggregate(32, fn, "y")
     assert ref.window_aggregate(32, fn, "y") == pytest.approx(direct)
-    assert sh.window_aggregate(32, fn, "y") == pytest.approx(direct)
+    assert got == pytest.approx(direct)
+    if rings == 1 or data == "integer":
+        # one ring is the reference layout; integer-valued partial sums
+        # add up exactly, so every layout matches it bit for bit
+        assert got == ref.window_aggregate(32, fn, "y")
     # repeat ticks over the same window are memoized (the rolling path)
     before = sh.agg_computes
     sh.window_aggregate(32, fn, "y")
@@ -96,7 +137,7 @@ def test_sharded_ops_via_bql_are_shard_transparent():
 
 
 def test_sharded_drop_accounting_sums_shards():
-    _, sh = _mk_pair(shards=2, capacity=16, block_rows=2)
+    sh = _mk(2, capacity=16, block_rows=2)
     sh.append({"x": np.arange(40, dtype=float),
                "y": np.arange(40, dtype=float)})
     assert sh.total_appended == 40
@@ -124,9 +165,10 @@ def test_stream_route_moves_live_state():
         np.asarray(moved.snapshot().columns["seq"]), np.arange(12, 20))
     moved.append({"x": [99.0]})                        # watermark continues
     assert moved.total_appended == 21
-    # rolling state travelled too: O(1) range sums still correct
-    assert moved.range_sum("x", 0, 8) == pytest.approx(
-        np.arange(13, 21).sum() + 99 - 20)
+    # rolling state travelled too: O(1) window sums still correct
+    # (window [14, 21) holds values 14..19 and the new 99)
+    assert moved.window_aggregate(7, "sum", "x") == pytest.approx(
+        np.arange(14, 20).sum() + 99)
 
 
 def test_stream_route_rejects_non_streams():
@@ -295,7 +337,7 @@ def test_empty_batch_append_is_a_noop():
     assert s.append({"x": []}) == {"appended": 0, "dropped": 0, "rows": 0}
     s.append({"x": [1.0, 2.0]})
     assert s.append({"x": []})["rows"] == 2
-    _, sh = _mk_pair(shards=2)
+    sh = _mk(2)
     assert sh.append({"x": [], "y": []})["appended"] == 0
     assert sh.num_rows == 0
 
@@ -307,11 +349,13 @@ def test_rolling_sums_reanchor_each_ring_generation():
     s = Stream("r", ("x",), capacity=8)
     s.append({"x": np.full(8, 1e9)})
     assert s.window_aggregate(8, "sum", "x") == pytest.approx(8e9)
-    assert "x" in s._cum                       # lazily built on first use
+    ring, = s.rings
+    assert "x" in ring.export_state()["cum"]   # lazily built on first use
     s.append({"x": np.full(56, 1e9)})          # crosses generations
     s.append({"x": np.arange(8, dtype=float)})  # crosses again
-    assert s._running["x"] == pytest.approx(np.arange(8).sum())
-    assert s.range_sum("x", 2, 6) == pytest.approx(2 + 3 + 4 + 5)
+    assert ring.export_state()["running"]["x"] == pytest.approx(
+        np.arange(8).sum())
+    assert s.window_aggregate(4, "sum", "x") == pytest.approx(4 + 5 + 6 + 7)
     assert s.window_aggregate(8, "sum", "x") == pytest.approx(28.0)
 
 
@@ -327,16 +371,19 @@ def test_rolling_sums_stay_precise_with_large_magnitudes():
     for _ in range(2000):                      # 128k rows, 64 per batch
         s.append({"t": rng.uniform(1e12, 2e12, 64)})
     k = s.total_appended // 128 - 1
-    first, arrs = s.ordered_arrays()           # raw float64 ring values
-    exact = float(arrs["t"][k * 128 - first:(k + 1) * 128 - first].sum())
+    cols = s.rings[0].export_state()["cols"]   # raw float64 ring values
+    seqs = cols["__seq"]
+    exact = float(cols["t"][(seqs >= k * 128)
+                            & (seqs < (k + 1) * 128)].sum())
     assert abs(s.window_aggregate(128, "sum", "t") - exact) < 1.0
 
 
 def test_scatter_vectorized_path_matches_segment_path():
     """A batch spanning many small blocks takes the vectorized owner
     path; distribution and gather must match the segment path exactly."""
-    ref, sh_seg = _mk_pair(shards=3, capacity=4096, block_rows=4)
-    _, sh_vec = _mk_pair(shards=3, capacity=4096, block_rows=4)
+    ref = Stream("s", ("x", "y"), 4096)
+    sh_seg = _mk(3, capacity=4096)
+    sh_vec = _mk(3, capacity=4096)
     rng = np.random.default_rng(8)
     batch = {"x": rng.standard_normal(600), "y": rng.standard_normal(600)}
     ref.append(batch)
@@ -354,7 +401,7 @@ def test_scatter_vectorized_path_matches_segment_path():
 
 def test_nan_shard_key_routes_deterministically():
     import warnings
-    _, sh = _mk_pair(shards=2, fields=("k", "v"), shard_key="k")
+    sh = _mk(2, fields=("k", "v"), shard_key="k")
     with warnings.catch_warnings():
         warnings.simplefilter("error")       # any RuntimeWarning fails
         sh.append({"k": [1.0, float("nan"), float("inf"),
